@@ -81,9 +81,6 @@ async def run(args: argparse.Namespace) -> int:
         # the coordination service (the child gets the kwargs below).
         import jax
 
-        from distributed_tpu.ops.partition import _pin_cpu_if_requested
-
-        _pin_cpu_if_requested(jax)
         if args.jax_cpu_devices:
             jax.config.update("jax_num_cpu_devices", args.jax_cpu_devices)
         from distributed_tpu.parallel import multihost

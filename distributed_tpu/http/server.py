@@ -354,10 +354,16 @@ def build_info_lines(role: str) -> list[str]:
     """``dtpu_build_info`` — the standard always-1 identity gauge: which
     build/runtime is behind this /metrics endpoint (version, jax
     version, backend, engine-mesh layout).  Label values are computed
-    once per process; a jax import failure degrades to empty labels
-    rather than breaking the scrape."""
+    once per process, once a backend is up.
+
+    The scrape never initializes a jax backend itself: on a host with
+    one chip, the process that initializes first holds it, and a worker
+    serving /metrics must not take it from the scheduler.  Until this
+    process has initialized a backend on its own, ``backend`` is ""."""
     cached = _BUILD_INFO_CACHE.get(role)
     if cached is None:
+        import sys
+
         from distributed_tpu import config
 
         version = jax_version = backend = ""
@@ -368,19 +374,18 @@ def build_info_lines(role: str) -> list[str]:
         # graft-lint: allow[swallowed-exceptions] identity labels degrade to ""
         except Exception:
             pass
-        try:
+        if "jax" in sys.modules:
             import jax
+            from jax._src import xla_bridge
 
             jax_version = str(jax.__version__)
-            backend = str(jax.default_backend())
-        # graft-lint: allow[swallowed-exceptions] identity labels degrade to ""
-        except Exception:
-            pass
+            if xla_bridge.backends_are_initialized():
+                backend = str(jax.default_backend())
         mesh = (
             f"{config.get('scheduler.jax.mesh.enabled')}"
             f"/{config.get('scheduler.jax.mesh.layout')}"
         )
-        cached = _BUILD_INFO_CACHE[role] = prom_line(
+        cached = prom_line(
             "dtpu_build_info", 1,
             {
                 "role": role,
@@ -393,6 +398,8 @@ def build_info_lines(role: str) -> list[str]:
                   "version, jax version, backend and engine-mesh layout)",
             type_="gauge",
         )
+        if backend:
+            _BUILD_INFO_CACHE[role] = cached
     return [cached]
 
 

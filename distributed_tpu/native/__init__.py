@@ -8,15 +8,17 @@ missing toolchain degrades gracefully.  ``DTPU_NATIVE_DISABLE=1``
 forces the pure-python fallbacks everywhere (the no-toolchain path,
 testable on a box that has g++).
 
-Rebuild keying: the library is stale when any source is newer than it
-OR when the compile command (flags + source list) changed since it was
-built — the command is recorded in a ``.buildinfo`` sidecar, so editing
-``_SOURCES`` or the flags takes effect without touching a source file.
+Rebuild keying: the library is stale when the content hash of any
+source, the source list or the flags differ from what the ``.buildinfo``
+sidecar recorded at build time.  Content, not mtimes: a copied tree
+(the chip tool copies the working tree, untracked ``.so`` included) can
+carry a library newer than sources it was not built from.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import logging
 import os
@@ -50,32 +52,34 @@ def disabled() -> bool:
 
 
 def _build_spec() -> dict:
-    """The identity of the compile command: what the ``.buildinfo``
-    sidecar records and what staleness is keyed on (basenames so a
-    relocated checkout does not rebuild)."""
-    return {
-        "flags": list(_FLAGS),
-        "sources": [os.path.basename(s) for s in _SOURCES],
-    }
+    """The identity of the build: what the ``.buildinfo`` sidecar
+    records and what staleness is keyed on — the flags and, per source
+    (by basename, so a relocated checkout does not rebuild), the sha256
+    of its content."""
+    sources = {}
+    for src in _SOURCES:
+        with open(src, "rb") as f:
+            sources[os.path.basename(src)] = hashlib.sha256(
+                f.read()
+            ).hexdigest()
+    return {"flags": list(_FLAGS), "sources": sources}
 
 
 def _needs_build() -> bool:
     if not os.path.exists(_LIB_PATH):
         return True
-    # command drift: editing _SOURCES or _FLAGS must invalidate the
-    # library even when no source file mtime moved
     try:
         with open(_BUILDINFO_PATH) as f:
             recorded = json.load(f)
     except (OSError, ValueError):
         return True
-    if recorded != _build_spec():
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(src) > lib_mtime for src in _SOURCES)
+    return recorded != _build_spec()
 
 
 def _build() -> bool:
+    # the spec is taken before compiling: a source edited mid-build
+    # leaves a sidecar that no longer matches, so the next load rebuilds
+    spec = _build_spec()
     cmd = ["g++", *_FLAGS, *_SOURCES, "-o", _LIB_PATH]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
@@ -89,7 +93,7 @@ def _build() -> bool:
         return False
     try:
         with open(_BUILDINFO_PATH, "w") as f:
-            json.dump(_build_spec(), f)
+            json.dump(spec, f)
     except OSError as e:  # stale-able but functional
         logger.warning("could not record native buildinfo: %s", e)
     return True

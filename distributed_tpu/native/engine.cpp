@@ -74,7 +74,7 @@ enum Op : int32_t {
 enum Status : int32_t { R_DONE = 0, R_ESCAPE = 1, R_TAPE_FULL = 2 };
 
 // escape reasons (the dtpu_engine_native_escapes_total breakdown and
-// the tests' escape-taxonomy assertions)
+// the tests' escape-class assertions)
 enum EscapeWhy : int32_t {
     E_UNCOMPILED_EDGE = 0,
     E_ACTOR = 1,

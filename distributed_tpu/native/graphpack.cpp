@@ -201,8 +201,8 @@ int64_t graphpack_topo(
 
 // Streamed-pack phase 2: fill sorted rows [i0, i1) of the per-task
 // arrays the device kernel consumes.  Chunked so the python driver can
-// overlap later fills with the (async) upload of earlier chunks — on
-// tunneled backends the pack CPU hides entirely behind the H2D wire.
+// overlap later fills with the (async) upload of earlier chunks and with
+// the device's work on earlier waves.
 void graphpack_fill(
     int64_t i0, int64_t i1,
     const float* durations, const float* out_bytes,

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from distributed_tpu.ops.partition import shard_map_compat
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -89,11 +88,12 @@ def _shuffle_program(mesh: Mesh, axis: str, n_dev: int, B: int,
         return recv_k, recv_v, recv_c, sent_c
 
     in_specs = (P(axis), P(axis)) + ((P(axis),) if masked else ())
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
+        check_vma=False,
     )
     return jax.jit(shard)
 
@@ -170,8 +170,9 @@ def _ring_program(mesh: Mesh, axis: str, shift: int):
     def local(x_l):
         return lax.ppermute(x_l, axis, perm)
 
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         local, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+        check_vma=False,
     )
     return jax.jit(shard)
 

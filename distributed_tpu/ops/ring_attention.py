@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_tpu.ops.partition import shard_map_compat
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -89,11 +88,12 @@ def _ring_program(mesh: Mesh, axis: str, causal: bool, scale: float):
         out = acc / jnp.maximum(l, 1e-30).T[:, :, None]
         return out.astype(ql.dtype)
 
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )
     return jax.jit(shard)
 

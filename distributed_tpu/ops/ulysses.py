@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_tpu.ops.partition import shard_map_compat
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -78,11 +77,12 @@ def _ulysses_program(mesh: Mesh, axis: str, causal: bool, scale: float):
         out = _local_attention(q, k, v, causal, scale)
         return heads_to_seq(out)
 
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )
     return jax.jit(shard)
 

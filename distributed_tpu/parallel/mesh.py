@@ -23,9 +23,8 @@ Scales the scheduler kernels beyond one chip the TPU way (SURVEY.md §2.3
 The [B, W] cost matrix only ever exists as [B/dt, W/dw] tiles, one per
 device.  Dependency edge lists are replicated (they are O(E) ints) and each
 task-shard masks the edges that land in its row range — bandwidth-cheap and
-keeps the segment-sum local.  ``shard_map`` (via
-``ops.partition.shard_map_compat``, version-tolerant across the jax 0.4/0.7
-API split) keeps the collectives explicit; XLA lowers them onto ICI.
+keeps the segment-sum local.  ``jax.shard_map`` keeps the collectives
+explicit; XLA lowers them onto ICI.
 """
 
 from __future__ import annotations
@@ -36,7 +35,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_tpu.ops.placement import WorkerArrays, PlacementBatch
-from distributed_tpu.ops.partition import make_engine_mesh, shard_map_compat
+from distributed_tpu.ops.partition import make_engine_mesh
 
 
 def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
@@ -143,7 +142,7 @@ def sharded_decide_workers(
     if restrict is None:
         restrict = jnp.ones((B, W), bool)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(
@@ -154,6 +153,7 @@ def sharded_decide_workers(
             P("tasks", "workers"),                # restrict tiles
         ),
         out_specs=P("tasks"),
+        check_vma=False,
     )
     with mesh:
         return fn(

@@ -769,22 +769,17 @@ class WorkStealing:
         if mirror is not None:
             # device-resident fleet half: the cached arrays re-upload
             # only rows dirtied since the last cycle — a fresh mirror
-            # dispatches the kernel with ZERO fleet H2D traffic.  The
-            # in-flight overlay (usually empty) lands as an O(#in-flight)
-            # device-side scatter-add.
+            # dispatches the kernel with ZERO fleet H2D traffic.  With
+            # an in-flight overlay (usually empty) the kernel reads the
+            # host occupancy it was already applied to: one O(cap)
+            # upload, and no device program whose shape follows the
+            # number of in-flight steals.
             dv = mirror.device_view(
                 ("nthreads", "occupancy", "running", "idle")
             )
             if dv is not None:
-                occ_kernel = dv["occupancy"]
-                if overlay_slots:
-                    import jax.numpy as jnp
-
-                    occ_kernel = occ_kernel.at[
-                        jnp.asarray(np.asarray(overlay_slots, np.int32))
-                    ].add(
-                        jnp.asarray(np.asarray(overlay_vals, np.float32))
-                    )
+                if not overlay_slots:
+                    occ_kernel = dv["occupancy"]
                 nthreads_kernel = dv["nthreads"]
                 idle_kernel = dv["idle"]
                 running_kernel = dv["running"]

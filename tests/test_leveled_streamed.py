@@ -1,6 +1,6 @@
 """Streamed pack+place driver (ops/leveled.place_graph_streamed): the
 pipelined fill/upload/dispatch path must produce the same placements as
-the one-shot driver, and the compact 11 B/task wire format must keep
+the one-shot driver, and the opt-in compact 11 B/task wire format must keep
 placement validity and load quality.
 
 Role model: the reference keeps its scheduler decisions identical under
@@ -123,14 +123,9 @@ def test_streamed_compact_valid_and_balanced():
 
 
 @needs_native
-def test_streamed_auto_compact_is_exact_on_cpu():
-    """compact="auto" (the default) disables the lossy wire format on the
-    cpu backend, so the chunked pack/upload overlap is byte-identical to
-    the unchunked path there."""
-    import jax
-
-    if jax.default_backend() != "cpu":
-        pytest.skip("auto resolves to packed on accelerator backends")
+def test_streamed_default_wire_is_exact():
+    """The default wire is the exact f16 format on every backend, so the
+    chunked pack/upload overlap is byte-identical to the unchunked path."""
     rng = np.random.default_rng(21)
     durations, out_bytes, src, dst = random_dag(rng, 20_000)
     nthreads, occ0, running = workers(8)

@@ -374,7 +374,7 @@ def test_check_mode_catches_injected_divergence(monkeypatch):
         _drive(nat, seed=4)
 
 
-def test_escape_taxonomy_rootish_and_actor():
+def test_escape_classes_rootish_and_actor():
     """Rootish groups (dep-free, width > 2x total threads) and actors
     escape to the oracle with the right labels, and outputs still
     match."""
@@ -514,9 +514,9 @@ print("FALLBACK_OK")
 
 
 def test_needs_build_keys_on_flags_and_source_list(tmp_path, monkeypatch):
-    """The mtime check alone left a stale .so loaded when _SOURCES or
-    the flags changed; _needs_build must also key on the recorded
-    compile command (the .buildinfo sidecar)."""
+    """_needs_build keys on the recorded build (the .buildinfo
+    sidecar): a changed source list or flag set makes the library
+    stale."""
     lib = tmp_path / "fake.so"
     lib.write_bytes(b"x")
     info = tmp_path / "fake.so.buildinfo"
@@ -543,6 +543,27 @@ def test_needs_build_keys_on_flags_and_source_list(tmp_path, monkeypatch):
         native, "_FLAGS", list(native._FLAGS) + ["-DX"]
     )
     assert native._needs_build(), "flag drift went unnoticed"
+
+
+def test_needs_build_keys_on_source_content_not_mtime(tmp_path, monkeypatch):
+    """A library newer than its sources is still stale when a source's
+    content differs from what it was built from (a copied tree can
+    carry such a library); a touched but unchanged source is not."""
+    lib = tmp_path / "fake.so"
+    info = tmp_path / "fake.so.buildinfo"
+    src = tmp_path / "a.cpp"
+    src.write_text("// v1")
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(native, "_BUILDINFO_PATH", str(info))
+    monkeypatch.setattr(native, "_SOURCES", [str(src)])
+    lib.write_bytes(b"x")
+    info.write_text(__import__("json").dumps(native._build_spec()))
+    assert not native._needs_build()
+    os.utime(str(src), (1, 1))  # far older than the library
+    assert not native._needs_build(), "an mtime-only touch rebuilt"
+    src.write_text("// v2")
+    os.utime(str(src), (1, 1))
+    assert native._needs_build(), "a content edit went unnoticed"
 
 
 def test_min_flood_routes_small_floods_to_oracle():

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
 import time as _time
 
 from distributed_tpu.client.client import Client
@@ -965,6 +966,33 @@ async def test_route_index_ledger_and_build_info_live():
             text = body.decode()
             assert "dtpu_ledger_rows_total" in text
             assert "dtpu_ledger_joined_total" in text
+
+
+def test_build_info_never_initializes_a_jax_backend():
+    """A process serving /metrics (a worker, say) must not take the chip
+    by scraping its identity: the backend label stays empty until the
+    process has initialized a backend on its own."""
+    import subprocess
+    import sys
+
+    code = (
+        "import jax\n"
+        "from jax._src import xla_bridge\n"
+        "from distributed_tpu.http.server import build_info_lines\n"
+        "first = build_info_lines('worker')[0]\n"
+        "assert not xla_bridge.backends_are_initialized(), first\n"
+        "assert 'backend=\"\"' in first, first\n"
+        "jax.devices()\n"
+        "later = build_info_lines('worker')[0]\n"
+        "assert 'backend=\"cpu\"' in later, later\n"
+        "print('OK')\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=repo, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout
 
 
 def test_rate_limiter_filter():

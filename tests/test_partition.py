@@ -10,9 +10,9 @@ import pytest
 
 from distributed_tpu.ops.partition import (
     block_init,
-    jax_available,
     partition_jax,
     partition_numpy,
+    partition_padded,
 )
 
 
@@ -97,7 +97,6 @@ def test_partition_trivial_cases():
     assert (one == 0).all()
 
 
-@pytest.mark.skipif(not jax_available(), reason="jax backend unavailable")
 def test_partition_jax_matches_numpy():
     keys, dur, wts, src, dst = _blockwise_graph(8)
     W = 6
@@ -106,6 +105,29 @@ def test_partition_jax_matches_numpy():
     b = partition_jax(dur, wts, src, dst, W, init=init)
     # identical algorithm, identical deterministic updates
     assert (a == b).all()
+
+
+def test_partition_padded_second_call_compiles_nothing():
+    """The kernel is jitted once at module level: a second call with the
+    same shapes and worker count reuses the compiled program, so a live
+    plan under the dense cap pays no backend compile after the first."""
+    import jax.monitoring
+
+    keys, dur, wts, src, dst = _blockwise_graph(8)
+    first = partition_padded(dur, wts, src, dst, 6)
+    compiles: list[str] = []
+
+    def listener(event: str, duration: float, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        again = partition_padded(dur, wts, src, dst, 6)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
+    assert (first == again).all()
 
 
 def test_live_planner_partitions_and_wins_locality():

@@ -270,7 +270,7 @@ def test_jax_placement_mesh_plan_path_and_stats():
         assert ss["full_packs"] == [1, 1]
 
 
-def test_jax_placement_mesh_auto_default():
+def test_jax_placement_mesh_auto_default(monkeypatch):
     """``scheduler.jax.mesh.enabled`` defaults to "auto" (ROADMAP item
     2 leftover): on when more than one device is visible at mesh-build
     time, single-device path otherwise, explicit booleans force."""
@@ -289,8 +289,10 @@ def test_jax_placement_mesh_auto_default():
     # auto on a 1-device host: the single-device path (a 1x1 mesh is
     # bit-identical but pays dispatch overhead for nothing)
     single = JaxPlacement(min_batch=4, min_workers=0, sync=True)
-    single._n_visible = lambda: 1  # instance shadow of the probe
-    assert single._get_mesh(build=True) is None
+    all_devices = jax.devices()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a: all_devices[:1])
+        assert single._get_mesh(build=True) is None
 
     # auto on this multi-device host: the mesh builds
     if len(jax.devices()) >= 2:
