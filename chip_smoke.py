@@ -45,22 +45,14 @@ import numpy as np
 #: cost-model bandwidth of every engine call here (bench.py's)
 BANDWIDTH = 100e6
 
-#: for the sharded engine against the single-device one (not
-#: element-wise equal at 10M tasks / 4096 workers: the mesh's psum
-#: re-associates the wave-load sums and near-ties in the spread order
-#: flip), the least share of tasks that must still agree, besides
-#: validate_leveled and the bounds in :func:`quality_parity`
-AGREEMENT_FLOOR = 0.5
-
-
 def say(msg: str) -> None:
     print(f"# {msg}", flush=True)
 
 
 class CompileCounter(logging.Handler):
-    """Counts XLA backend compiles (and their seconds) while active, and
-    lists the compiled programs with their argument shapes (from JAX's
-    ``jax_log_compiles`` records, which are kept off the console)."""
+    """Counts XLA backend compiles (and their seconds) while active.
+    JAX's ``jax_log_compiles`` records are kept off the console and
+    only quoted when a compile is a failure."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -68,7 +60,7 @@ class CompileCounter(logging.Handler):
         super().__init__(logging.DEBUG)
         self.n = 0
         self.seconds = 0.0
-        self.programs: list[str] = []
+        self.log: list[str] = []
 
     def _listen(self, event: str, duration: float, **kw) -> None:
         if event == self.EVENT:
@@ -76,12 +68,7 @@ class CompileCounter(logging.Handler):
             self.seconds += duration
 
     def emit(self, record: logging.LogRecord) -> None:
-        msg = record.getMessage()
-        if msg.startswith("Compiling ") and " with global shapes" in msg:
-            name, _, shapes = msg[len("Compiling "):].partition(
-                " with global shapes and types "
-            )
-            self.programs.append(f"{name} {shapes.split('. Argument')[0]}")
+        self.log.append(record.getMessage()[:300])
 
     def __enter__(self) -> "CompileCounter":
         import jax
@@ -131,40 +118,24 @@ def phase_identity() -> dict:
 # --------------------------------------------------------------- phase 2
 
 
-def quality_parity(ref, res, n_workers: int, exact: bool) -> dict:
-    """Agreement of two placements of one graph.  ``exact`` requires
-    element-wise equality; otherwise the bounds that hold when they are
-    not equal: load imbalance within 5 % of the reference, equal total
-    modeled load, the same choice mix."""
+def parity(ref, res, n_workers: int) -> dict:
+    """Two placements of one graph must be equal element by element;
+    the load imbalance of each is reported beside the agreement."""
     agreement = float((ref.assignment == res.assignment).mean())
-    counts_ref = np.bincount(ref.assignment, minlength=n_workers)
-    counts = np.bincount(res.assignment, minlength=n_workers)
-    imb_ref = float(counts_ref.max() / max(counts_ref.mean(), 1))
-    imb = float(counts.max() / max(counts.mean(), 1))
-    occ_ref = float(ref.occupancy.sum())
-    occ_rel = abs(float(res.occupancy.sum()) - occ_ref) / max(occ_ref, 1e-9)
-    mix_ref = np.bincount(ref.choice, minlength=3) / len(ref.choice)
-    mix = np.bincount(res.choice, minlength=3) / len(res.choice)
+
+    def imbalance(assignment) -> float:
+        counts = np.bincount(assignment, minlength=n_workers)
+        return float(counts.max() / max(counts.mean(), 1))
+
     out = {
         "agreement": agreement,
-        "imbalance_ref": imb_ref,
-        "imbalance": imb,
-        "occupancy_rel_diff": occ_rel,
-        "choice_mix_max_diff": float(np.abs(mix_ref - mix).max()),
+        "imbalance_ref": imbalance(ref.assignment),
+        "imbalance": imbalance(res.assignment),
     }
     if agreement < 1.0:
         first = int(np.flatnonzero(ref.assignment != res.assignment)[0])
         out["first_diff_level"] = int(ref.level[first])
-        if exact:
-            raise AssertionError(f"placements differ: {out}")
-        if agreement < AGREEMENT_FLOOR:
-            raise AssertionError(f"agreement below the floor: {out}")
-        if imb > imb_ref * 1.05 + 0.01:
-            raise AssertionError(f"load imbalance regressed: {out}")
-        if occ_rel >= 1e-3:
-            raise AssertionError(f"total modeled load diverged: {out}")
-        if out["choice_mix_max_diff"] >= 0.05:
-            raise AssertionError(f"choice mix diverged: {out}")
+        raise AssertionError(f"placements differ: {out}")
     return out
 
 
@@ -212,7 +183,7 @@ def phase_whole_graph(graph, n_workers: int, threads: int = 2,
     # the chip must place exactly as the host CPU does (measured equal
     # on a v5e, PR 21): f16->f32 converts, 1/threads, the scatter-add
     # load sums and the stable argsort agree bit for bit there
-    parity = quality_parity(ref, res, n_workers, exact=True)
+    same = parity(ref, res, n_workers)
     out = {
         "tasks": len(durations),
         "workers": n_workers,
@@ -226,7 +197,7 @@ def phase_whole_graph(graph, n_workers: int, threads: int = 2,
         "compile_s_warmup": warm.seconds,
         "compiles_timed": timed.n,
         "assigned": int((assignment >= 0).sum()),
-        **parity,
+        **same,
     }
     if timed.n:
         raise AssertionError(f"the timed call compiled: {out}")
@@ -236,16 +207,6 @@ def phase_whole_graph(graph, n_workers: int, threads: int = 2,
 # --------------------------------------------------------------- phase 3
 
 _MOD = 1_000_003
-
-#: the programs whose row bucket follows live load — the stealable set,
-#: the replicated keys, the mirror's dirty fleet rows: each pow2 bucket
-#: compiles once, the first time the load reaches it, so a second
-#: submission can still meet one for the first time.  Every other
-#: program must compile nothing on the second submission.
-LOAD_FOLLOWING = (
-    "jit(_steal_rounds)", "jit(_drop_rounds)", "jit(_scatter_rows)",
-)
-
 
 def _node(salt, *deps):
     return (salt * 31 + sum(deps)) % _MOD
@@ -395,14 +356,21 @@ async def _live_cluster(n_workers, threads, n_tasks, n_replicate, seed,
                         ]._device_plan_inflight,
                         timeout, "in-flight device plans to land",
                     )
+                    # the planner's ahead-of-time compiles for the
+                    # fleet's capacity (every row bucket of the steal,
+                    # AMM and mirror-sync programs)
+                    await _wait_for(
+                        lambda: placement.precompiled is not None
+                        and placement.precompiled.done(),
+                        timeout, "the planner's precompile job",
+                    )
+                    out["precompiled"] = placement.precompiled.result()
                 out["first_compiles"] = first.n
                 out["first_compile_s"] = first.seconds
                 with CompileCounter() as second:
                     _, _, out["second_wall_s"] = await run("b")
                 out["second_compiles"] = second.n
-                out["second_compiled"] = second.programs
-                unexpected = [p for p in second.programs
-                              if not p.startswith(LOAD_FOLLOWING)]
+                second_log = second.log
             out.update(counts)
             out["plans_computed"] = placement.plans_computed
             out["plan_hits"] = placement.plan_hits
@@ -421,8 +389,8 @@ async def _live_cluster(n_workers, threads, n_tasks, n_replicate, seed,
         problems.append("no device steal cycle")
     if out["amm_drop_plans"] < 1:
         problems.append("no device ReduceReplicas round")
-    if unexpected:
-        problems.append(f"the second submission compiled {unexpected}")
+    if out["second_compiles"]:
+        problems.append(f"the second submission compiled: {second_log}")
     if problems:
         raise AssertionError(f"{'; '.join(problems)}: {out}")
     return out
@@ -527,7 +495,7 @@ def phase_four_chips(graph, n_workers: int, n_devices: int = 4,
     if not all(_spans_devices(a, n_devices) for a in fleet_dev.values()):
         raise AssertionError("mirror fleet shards do not span the mesh")
     out["shards"] = stats["shards"]
-    out.update(quality_parity(single, sharded, n_workers, exact=False))
+    out.update(parity(single, sharded, n_workers))
 
     shuffle_mesh = make_mesh_1d(n_devices)
     rng = np.random.default_rng(1)

@@ -350,6 +350,24 @@ def census_metric_lines(census: Any) -> list[str]:
 _BUILD_INFO_CACHE: dict[str, str] = {}
 
 
+def _initialized_backend() -> str:
+    """The default jax backend's name once this process has initialized
+    one, else "" — never initializes one itself.  jax has no public
+    query for this: the private one is read here alone, and should it
+    move, the answer is "not initialized"."""
+    try:
+        from jax._src import xla_bridge
+
+        initialized = xla_bridge.backends_are_initialized()
+    except (ImportError, AttributeError):
+        return ""
+    if not initialized:
+        return ""
+    import jax
+
+    return str(jax.default_backend())
+
+
 def build_info_lines(role: str) -> list[str]:
     """``dtpu_build_info`` — the standard always-1 identity gauge: which
     build/runtime is behind this /metrics endpoint (version, jax
@@ -376,11 +394,9 @@ def build_info_lines(role: str) -> list[str]:
             pass
         if "jax" in sys.modules:
             import jax
-            from jax._src import xla_bridge
 
             jax_version = str(jax.__version__)
-            if xla_bridge.backends_are_initialized():
-                backend = str(jax.default_backend())
+            backend = _initialized_backend()
         mesh = (
             f"{config.get('scheduler.jax.mesh.enabled')}"
             f"/{config.get('scheduler.jax.mesh.layout')}"

@@ -19,7 +19,6 @@ application point (ties broken toward the lowest worker index).
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
@@ -39,8 +38,19 @@ class DropBatch(NamedTuple):
     mem: np.ndarray       # f32[W] projected managed memory per worker
 
 
-@functools.partial(jax.jit, static_argnames=("K",))
-def _drop_rounds(holders, excluded, nbytes, ndrop, mem, K: int):
+#: most replicated-task rows in one device call; a larger round runs as
+#: consecutive calls, each starting from the memory projection the
+#: previous one left
+MAX_ROWS = 4096
+#: most drop rounds per call (the output's static width); the rounds
+#: actually run are a traced bound, so they never enter the program shape
+MAX_ROUNDS = 64
+#: smallest row bucket
+MIN_ROWS = 64
+
+
+@jax.jit
+def _drop_rounds(holders, excluded, nbytes, ndrop, mem, K):
     R, W = holders.shape
     NEG = jnp.float32(-np.inf)
 
@@ -67,11 +77,33 @@ def _drop_rounds(holders, excluded, nbytes, ndrop, mem, K: int):
         drops = drops.at[:, k].set(jnp.where(ok, w, -1).astype(jnp.int32))
         return holders, ndrop, mem, drops
 
-    drops0 = jnp.full((R, K), -1, jnp.int32)
+    drops0 = jnp.full((R, MAX_ROUNDS), -1, jnp.int32)
     _, _, mem, drops = jax.lax.fori_loop(
         0, K, round_body, (holders, ndrop, mem, drops0)
     )
     return drops, mem
+
+
+def _row_buckets():
+    b = MIN_ROWS
+    while b <= MAX_ROWS:
+        yield b
+        b <<= 1
+
+
+def lower_all(W: int):
+    """Yield ``_drop_rounds`` lowered for every row bucket a round can
+    use at fleet width ``W`` (the mirror's capacity): compiled ahead,
+    a live AMM cycle — which runs on the event loop — never compiles."""
+    for R in _row_buckets():
+        yield _drop_rounds.lower(
+            jax.ShapeDtypeStruct((R, W), jnp.bool_),
+            jax.ShapeDtypeStruct((R, W), jnp.bool_),
+            jax.ShapeDtypeStruct((R,), jnp.float32),
+            jax.ShapeDtypeStruct((R,), jnp.int32),
+            jax.ShapeDtypeStruct((W,), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        )
 
 
 def plan_drop_rounds(
@@ -80,45 +112,48 @@ def plan_drop_rounds(
     """Select replica drops on device; returns rounds of
     [(task_row, worker_idx)].  Drops within one round were selected
     against the same (round-start) memory projection — Jacobi, where the
-    python policy is Gauss-Seidel."""
+    python policy is Gauss-Seidel.  Rows beyond ``MAX_ROWS`` go in later
+    calls, whose rounds follow the earlier calls' rounds."""
     R = len(batch.nbytes)
     if R == 0:
         return []
     K = rounds if rounds is not None else int(max(batch.ndrop.max(), 1))
-    K = min(K, 64)
-    # pad rows and round-up K to pow2 buckets: repeated AMM cycles vary
-    # in replicated-task count every 2 s, and each distinct (R, K) shape
-    # would otherwise recompile the kernel
-    Kp = _bucket(K, floor=1)
-    Rp = _bucket(R, floor=64)
+    K = min(K, MAX_ROUNDS)
     W = batch.holders.shape[1]
-
-    def pad2(arr):
-        buf = np.zeros((Rp, W), bool)
-        buf[:R] = arr
-        return jnp.asarray(buf)
-
-    def pad1(arr, dtype):
-        buf = np.zeros(Rp, dtype)
-        buf[:R] = arr
-        return jnp.asarray(buf)
-
-    drops, _ = _drop_rounds(
-        pad2(batch.holders),
-        pad2(batch.excluded),
-        pad1(batch.nbytes, np.float32),
-        pad1(batch.ndrop, np.int32),
-        jnp.asarray(batch.mem, jnp.float32),
-        K=Kp,
-    )
-    # Kp > K padding rounds are shape-only: honor the caller's bound
-    drops = np.asarray(drops)[:R, :K]
+    mem = jnp.asarray(batch.mem, jnp.float32)
     out: list[list[tuple[int, int]]] = []
-    for k in range(drops.shape[1]):
-        col = drops[:, k]
-        rnd = [(int(r), int(col[r])) for r in np.nonzero(col >= 0)[0]]
-        if rnd:
-            out.append(rnd)
+    for lo in range(0, R, MAX_ROWS):
+        hi = min(lo + MAX_ROWS, R)
+        # pad rows to a pow2 bucket: repeated AMM cycles vary in
+        # replicated-task count every 2 s, and lower_all() covers
+        # exactly these buckets
+        Rp = _bucket(hi - lo, floor=MIN_ROWS)
+
+        def pad2(arr):
+            buf = np.zeros((Rp, W), bool)
+            buf[: hi - lo] = arr[lo:hi]
+            return jnp.asarray(buf)
+
+        def pad1(arr, dtype):
+            buf = np.zeros(Rp, dtype)
+            buf[: hi - lo] = arr[lo:hi]
+            return jnp.asarray(buf)
+
+        drops, mem = _drop_rounds(
+            pad2(batch.holders),
+            pad2(batch.excluded),
+            pad1(batch.nbytes, np.float32),
+            pad1(batch.ndrop, np.int32),
+            mem,
+            np.int32(K),
+        )
+        drops = np.asarray(drops)[: hi - lo, :K]
+        for k in range(K):
+            col = drops[:, k]
+            rnd = [(lo + int(r), int(col[r]))
+                   for r in np.nonzero(col >= 0)[0]]
+            if rnd:
+                out.append(rnd)
     return out
 
 

@@ -161,6 +161,25 @@ def _steal_rounds(
     return thief_of[:T], occ
 
 
+#: smallest task bucket
+MIN_TASKS = 64
+
+
+def lower_all(W: int, max_tasks: int, rounds: int = 8):
+    """Yield ``_steal_rounds`` lowered for every task bucket a balance
+    cycle of at most ``max_tasks`` tasks can use at fleet width ``W``
+    (the mirror's capacity): compiled ahead, a live cycle never
+    compiles."""
+    fleet = [jax.ShapeDtypeStruct((W,), d)
+             for d in (jnp.float32, jnp.int32, jnp.bool_, jnp.bool_)]
+    T = MIN_TASKS
+    while T <= _bucket(max_tasks, floor=MIN_TASKS):
+        task = [jax.ShapeDtypeStruct((T,), d)
+                for d in (jnp.int32, jnp.int32, jnp.float32, jnp.float32)]
+        yield _steal_rounds.lower(*task, *fleet, K=rounds)
+        T <<= 1
+
+
 def plan_steals(batch: StealBatch, rounds: int = 8) -> np.ndarray:
     """One balance cycle on device; returns thief worker index per task
     (-1 = not stolen).
@@ -172,7 +191,7 @@ def plan_steals(batch: StealBatch, rounds: int = 8) -> np.ndarray:
     T = len(batch.task_victim)
     if T == 0:
         return np.zeros(0, np.int32)
-    Tp = _bucket(T, floor=64)
+    Tp = _bucket(T, floor=MIN_TASKS)
 
     def pad(arr, fill, dtype):
         buf = np.full(Tp, fill, dtype)

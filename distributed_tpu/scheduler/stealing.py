@@ -583,6 +583,8 @@ class WorkStealing:
     # snapshot python-loop runs on the event loop and must stay O(bounded)
     DEVICE_MAX_VICTIMS = 32
     DEVICE_MAX_TASKS = 8192
+    #: the mirror fields the kernel reads from the device
+    DEVICE_FIELDS = ("nthreads", "occupancy", "running", "idle")
 
     # bounds on the thief-resident byte scan (event-loop work): skip
     # very wide tasks (the missing remainder dominates the price
@@ -774,9 +776,7 @@ class WorkStealing:
             # host occupancy it was already applied to: one O(cap)
             # upload, and no device program whose shape follows the
             # number of in-flight steals.
-            dv = mirror.device_view(
-                ("nthreads", "occupancy", "running", "idle")
-            )
+            dv = mirror.device_view(self.DEVICE_FIELDS)
             if dv is not None:
                 if not overlay_slots:
                     occ_kernel = dv["occupancy"]

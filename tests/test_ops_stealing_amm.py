@@ -138,13 +138,19 @@ def test_empty_steal_batch():
 # ---------------------------------------------------------------- ops.amm
 
 
-def test_drops_match_python_policy_invariants():
+@pytest.mark.parametrize("max_rows", [None, 64])
+def test_drops_match_python_policy_invariants(monkeypatch, max_rows):
     """Replaying device drops sequentially must satisfy the python
     oracle: never the last replica, never an excluded holder, always the
     max-projected-memory eligible holder at application time
-    (reference active_memory_manager.py:290,527)."""
+    (reference active_memory_manager.py:290,527).  ``max_rows`` splits
+    the round into device calls of that many rows."""
+    from distributed_tpu.ops import amm as ops_amm
+
     rng = np.random.default_rng(2)
-    R, W = 60, 12
+    R, W = 150, 12
+    if max_rows is not None:
+        monkeypatch.setattr(ops_amm, "MAX_ROWS", max_rows)
     holders = rng.random((R, W)) < 0.4
     holders[:, 0] |= ~holders.any(axis=1)  # at least one replica each
     excluded = (rng.random((R, W)) < 0.1) & holders

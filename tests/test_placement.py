@@ -295,7 +295,7 @@ def test_make_mesh_shapes():
 
 
 def test_sharded_leveled_matches_single_device():
-    """The sharded (data-parallel over waves, psum/all_gather per wave)
+    """The sharded (data-parallel over waves, all_gathers per wave)
     leveled engine must reproduce the single-device engine the live
     scheduler runs (parallel/mesh.py place_graph_leveled_sharded)."""
     import jax
@@ -326,8 +326,6 @@ def test_sharded_leveled_matches_single_device():
     a_sh, load_sh = place_graph_leveled_sharded(mesh, packed, nth, occ, run)
     res = place_graph_leveled(packed, nth, occ, run)
     assert (a_sh >= 0).all() and (a_sh < W).all()
-    # identical decisions (same math; psum order differences only shift
-    # float ties, which this graph does not exercise)
-    agree = (a_sh == res.assignment).mean()
-    assert agree > 0.99, agree
-    np.testing.assert_allclose(load_sh, res.occupancy, rtol=0.15, atol=1.0)
+    # identical decisions: the same math, summed in the same order
+    np.testing.assert_array_equal(a_sh, res.assignment)
+    np.testing.assert_array_equal(load_sh, res.occupancy)

@@ -129,15 +129,15 @@ def test_steal_rounds_512_workers(one_chip):
 
 
 def test_drop_rounds_512_workers(one_chip):
-    from distributed_tpu.ops.amm import _drop_rounds
+    from distributed_tpu.ops.amm import MAX_ROWS, _drop_rounds
 
-    R, W = 2048, 512
+    R, W = MAX_ROWS, 512
     args = (
         _spec((R, W), jnp.bool_, one_chip), _spec((R, W), jnp.bool_, one_chip),
         _spec((R,), jnp.float32, one_chip), _spec((R,), jnp.int32, one_chip),
-        _spec((W,), jnp.float32, one_chip),
+        _spec((W,), jnp.float32, one_chip), _spec((), jnp.int32, one_chip),
     )
-    _fits(_drop_rounds.lower(*args, K=4).compile())
+    _fits(_drop_rounds.lower(*args).compile())
 
 
 def test_partition_kernel_dense_limit(one_chip):
