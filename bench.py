@@ -911,7 +911,7 @@ def _sim_10k_once(seed: int, native: bool | None = None):
     report["wall_s"] = round(time.perf_counter() - t0, 1)
     report["n_tasks"] = trace.n_tasks
     report["engine_wall_s"] = round(
-        sim.state.wall.totals.get("engine.drain", 0.0), 1
+        sim.state.wall.snapshot().get("engine.drain", 0.0), 1
     )
     if sim.state.native is not None:
         report["native"] = sim.state.native.counters()

@@ -1,0 +1,10 @@
+"""Scheduler ingest self time (``WallBudget`` phases ``ingest.*``: graph
+intake and stream payload dispatch) in the window, per task completed."""
+
+
+def read(m):
+    wall, n = m.get("wall_delta"), m.get("tasks_done")
+    if wall is None or not n:
+        return None
+    got = [v for k, v in wall.items() if k.startswith("ingest.")]
+    return sum(got) / n * 1e6 if got else None

@@ -22,6 +22,7 @@ from typing import Any, Callable
 
 from distributed_tpu import config
 from distributed_tpu.comm.core import Comm, connect
+from distributed_tpu.diagnostics.selfprofile import WallBudget, loop_budget
 from distributed_tpu.exceptions import CommClosedError
 from distributed_tpu.graph.spec import Graph, Key, TaskRef, TaskSpec, tokenize
 from distributed_tpu.protocol.serialize import Serialize, unwrap
@@ -212,6 +213,9 @@ class Client:
         self._scheduler_identity: dict = {}  # last identity() snapshot
         self._generation = 0
         self.loop: asyncio.AbstractEventLoop | None = None
+        # the wall budget of the loop this client runs on (set at
+        # _start; diagnostics/selfprofile.py): client.* phases
+        self.wall = WallBudget()
         self._loop_runner: LoopRunner | None = None
         if not asynchronous:
             self._loop_runner = LoopRunner()
@@ -237,6 +241,7 @@ class Client:
 
     async def _start(self) -> "Client":
         self.loop = asyncio.get_running_loop()
+        self.wall = loop_budget()
         comm = await connect(self.address, **self._connection_args)
         await comm.write(
             {"op": "register-client", "client": self.id, "reply": False}
@@ -643,8 +648,9 @@ class Client:
     def compute_graph(self, graph: Graph, keys: list[Key], **kwargs: Any
                       ) -> dict[Key, Future]:
         """Submit a pre-built ``Graph`` (the collections entry point)."""
-        graph.validate()
-        return self._graph_to_futures(dict(graph.tasks), keys, **kwargs)
+        with self.wall.phase("client.submit"):
+            graph.validate()
+            return self._graph_to_futures(dict(graph.tasks), keys, **kwargs)
 
     # ------------------------------------------------------------- results
 
@@ -721,7 +727,8 @@ class Client:
             and st.status == "finished"
         ]
         data = await self._gather_keys(list(dict.fromkeys(keys)))
-        return _substitute_futures(futures, data, errors)
+        with self.wall.phase("client.gather"):
+            return _substitute_futures(futures, data, errors)
 
     def _ensure_tracked(self, key: Key) -> "FutureState":
         """Track a key learned out-of-band (queue/variable/dataset): register
@@ -742,10 +749,11 @@ class Client:
         for attempt in range(attempts):
             resp = await self.scheduler.gather(keys=keys)
             if resp.get("status") == "OK":
-                return {
-                    k: self._maybe_actor(unwrap(v))
-                    for k, v in resp["data"].items()
-                }
+                with self.wall.phase("client.gather"):
+                    return {
+                        k: self._maybe_actor(unwrap(v))
+                        for k, v in resp["data"].items()
+                    }
             missing = resp.get("keys", [])
             logger.warning("gather attempt %d missing %s", attempt, missing)
             await asyncio.sleep(0.1 * (attempt + 1))

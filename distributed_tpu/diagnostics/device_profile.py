@@ -10,7 +10,8 @@ time: XLA executions are dispatched asynchronously and the python stack
 only ever shows the dispatch call.  The TPU-native analog is the XLA
 runtime profiler — ``jax.profiler.start_trace``/``stop_trace`` capture
 a device timeline (TensorBoard/XProf ``plugins/profile`` format), and
-while tracing is active every task the worker executes runs under a
+while a profiler trace is active (started here or by anything else in
+the process) every task the worker executes runs under a
 ``jax.profiler.TraceAnnotation`` carrying its key, so device ops group
 under the task that launched them.  Same contract as the reference's
 merge: foreign (non-python) activity is attributed to the python-level
@@ -37,8 +38,13 @@ import os
 import tempfile
 import threading
 
+from distributed_tpu.tracing import TraceSpan, trace_active
+
 _lock = threading.Lock()
 _active_dir: str | None = None
+#: what :func:`annotate` hands out with no trace active: one shared
+#: object, so the worker's per-task path allocates nothing
+_NO_SPAN = contextlib.nullcontext()
 
 
 def available() -> bool:
@@ -49,12 +55,6 @@ def available() -> bool:
         return True
     except Exception:  # pragma: no cover - jax is baked into this image
         return False
-
-
-def active() -> bool:
-    """Cheap flag the worker's hot path checks before paying for a
-    TraceAnnotation object per task."""
-    return _active_dir is not None
 
 
 def start(logdir: str | None = None) -> dict:
@@ -108,13 +108,9 @@ def stop() -> dict:
 
 def annotate(key) -> contextlib.AbstractContextManager:
     """Context manager marking one task's execution on the device
-    timeline.  A no-op unless a trace is active (the hot path pays one
-    module-global read per task)."""
-    if _active_dir is None:
-        return contextlib.nullcontext()
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(str(key))
-    except Exception:  # pragma: no cover - defensive
-        return contextlib.nullcontext()
+    timeline of any active profiler trace; with none active it costs
+    one ``TraceAnnotation.is_enabled()`` (``tracing.trace_active``) and
+    returns a shared no-op."""
+    if not trace_active():
+        return _NO_SPAN
+    return TraceSpan(str(key))

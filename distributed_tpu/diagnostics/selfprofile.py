@@ -9,16 +9,19 @@ answered question ("where did the scheduler's second go?") with three
 cooperating pieces (docs/observability.md "Self-profiling"):
 
 - :class:`WallBudget` — exact monotonic-clock accumulators per
-  control-plane *phase* (``engine.drain``, ``egress.flush``,
-  ``kernel.dispatch``, ``mirror.upload``, ``telemetry.fold``, and —
-  opt-in, ``scheduler.profile.arm-attribution`` — the per-transition
+  control-plane *phase* (:data:`PHASES`, and — opt-in,
+  ``scheduler.profile.arm-attribution`` — the per-transition
   ``engine.scalar-arm:<start>,<finish>`` arms).  Phases are entered at
-  the existing hot-path seams in ``scheduler/state.py``,
-  ``scheduler/server.py``, ``scheduler/jax_placement.py`` and
-  ``scheduler/mirror.py``; totals export as
+  the hot-path seams of every role sharing an event loop (scheduler,
+  workers, clients) and never across an ``await``; totals export as
   ``dtpu_wall_seconds_total{phase=}`` at ``/metrics`` and the
   per-arm table is the payoff artifact ``sim.profile_run`` emits
-  (ROADMAP item 4's prioritization input).
+  (ROADMAP item 4's prioritization input).  One budget per event loop
+  (:func:`loop_budget`): on an in-process cluster the per-thread stack
+  gives true self time across roles, and ``loop.idle`` is the loop's
+  selector wait.  While a ``jax.profiler`` trace is active every phase
+  also shows on it as a ``TraceAnnotation``, on the device trace's
+  clock.
 - :class:`ControlPlaneProfiler` — a :class:`~distributed_tpu.
   diagnostics.profile.Profiler` aimed at the loop/planner thread idents,
   with a ``stop=`` frame boundary so the shared asyncio ``run_forever``
@@ -45,16 +48,23 @@ thread waits on an ``Event``, never ``time.sleep``.
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import sys
 import threading
 import traceback as _traceback
+import weakref
 from collections import deque
 from typing import Any, Callable, Iterable
 
 from distributed_tpu import config
 from distributed_tpu.diagnostics.profile import Profiler, create, merge, process
-from distributed_tpu.tracing import SECONDS_BUCKETS, Histogram, to_jsonl
+from distributed_tpu.tracing import (
+    SECONDS_BUCKETS,
+    Histogram,
+    to_jsonl,
+    trace_annotation,
+)
 from distributed_tpu.utils.misc import time
 
 logger = logging.getLogger("distributed_tpu.selfprofile")
@@ -71,7 +81,22 @@ PHASES = (
     "kernel.dispatch",   # a device placement plan (loop or planner thread)
     "mirror.upload",     # fleet-mirror device upload (scatter or full)
     "telemetry.fold",    # heartbeat telemetry folding into the aggregate
+    "loop.idle",         # the event loop's selector wait (loop_budget)
+    "ingest.graph",      # Scheduler.update_graph, update_graph_core inside
+    "ingest.stream",     # scheduler stream payload decode + dispatch
+    "plan.snapshot",     # JaxPlacement.plan_graph's filter + snapshot
+    "plan.merge",        # an async plan landing on the loop
+    "steal.cycle",       # one WorkStealing.balance cycle
+    "amm.cycle",         # one active-memory-manager round
+    "wshell.stream",     # worker stream payload decode + dispatch
+    "wshell.instructions",  # Worker.handle_stimulus -> _handle_instructions
+    "client.submit",     # Client.compute_graph's validation + packing
+    "client.gather",     # unpacking a gather's results
 )
+
+#: the loop's selector wait, added by the selector wrapper of
+#: :func:`loop_budget` straight to the totals (never on the stack)
+LOOP_IDLE = "loop.idle"
 
 #: innermost frames in these files mean "the loop is idle in select()" —
 #: counted apart so an idle scheduler's tree stays signal-dense
@@ -85,51 +110,77 @@ class WallBudget:
     """Exact wall attribution of control-plane threads by phase.
 
     A per-thread phase *stack* (entering a child phase pauses the
-    parent's accumulation, so every total is **self time**) plus shared
-    totals.  ``push``/``pop`` are the hot-path API (two monotonic reads
-    and a couple of dict operations each); :meth:`phase` is the
-    context-manager convenience for batch-level seams.  The top of each
-    thread's stack is published in ``_active`` so the sampler and stall
-    watchdog (other threads) can stamp the in-progress phase +
-    stimulus id onto samples and stall events.
+    parent's accumulation, so every total is **self time**) and
+    per-thread totals, summed by :meth:`snapshot`.  ``push``/``pop`` are
+    the hot-path API (a monotonic read and a few dict operations each,
+    with no lock: each thread folds into its own dicts); :meth:`phase`
+    is the context-manager convenience for batch-level seams.  The top
+    of each thread's stack is published in ``_active`` so the sampler
+    and stall watchdog (other threads) can stamp the in-progress phase +
+    stimulus id onto samples and stall events.  While a profiler trace
+    is active each pushed phase is also a ``jax.profiler``
+    ``TraceAnnotation`` of its name on the pushing thread
+    (:func:`distributed_tpu.tracing.trace_annotation`); entering and
+    leaving it is billed to no phase, so self times read the same
+    traced or not.
     """
 
     def __init__(self, clock: Callable[[], float] = time):
         # REAL monotonic clock even under the simulator: the budget
         # measures python cost, not virtual time (sim.profile_run)
         self.clock = clock
-        self.totals: dict[str, float] = {}
-        self.counts: dict[str, int] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
+        # (totals, counts) of every thread that ever billed here; a
+        # thread's dicts are written by that thread alone, and read by
+        # snapshot() as whole-dict copies (atomic under the GIL)
+        self._threads: list[tuple[dict[str, float], dict[str, int]]] = []
         # thread ident -> (phase, stimulus) of that thread's stack top
         self._active: dict[int, tuple[str, str]] = {}
 
     # ------------------------------------------------------------ hot path
 
-    def _stack(self) -> list:
-        st = getattr(self._tls, "stack", None)
-        if st is None:
-            st = self._tls.stack = []
-        return st
+    def _join(self) -> list:
+        """This thread's first use: its stack and dicts."""
+        tl = self._tls
+        tl.stack, tl.totals, tl.counts = [], {}, {}
+        with self._lock:
+            self._threads.append((tl.totals, tl.counts))
+        return tl.stack
 
     def push(self, phase: str, stim: str = "") -> None:
         now = self.clock()
-        st = self._stack()
+        tl = self._tls
+        try:
+            st = tl.stack
+        except AttributeError:
+            st = self._join()
         if st:
             top = st[-1]
-            self._fold(top[0], now - top[2], entered=False)
+            totals = tl.totals
+            totals[top[0]] = totals.get(top[0], 0.0) + (now - top[2])
             top[2] = now
-        st.append([phase, stim, now])
+        ann = trace_annotation(phase)
+        if ann is not None:  # the mirror's own cost is billed to no phase
+            now = self.clock()
+            if st:
+                st[-1][2] = now
+        st.append([phase, stim, now, ann])
         self._active[threading.get_ident()] = (phase, stim)
 
     def pop(self) -> None:
         now = self.clock()
-        st = self._stack()
+        tl = self._tls
+        st = getattr(tl, "stack", None)
         if not st:  # unbalanced pop: never corrupt the accumulators
             return
-        phase, _stim, seg = st.pop()
-        self._fold(phase, now - seg, entered=True)
+        phase, _stim, seg, ann = st.pop()
+        totals, counts = tl.totals, tl.counts
+        totals[phase] = totals.get(phase, 0.0) + (now - seg)
+        counts[phase] = counts.get(phase, 0) + 1
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            now = self.clock()
         ident = threading.get_ident()
         if st:
             top = st[-1]
@@ -138,14 +189,15 @@ class WallBudget:
         else:
             self._active.pop(ident, None)
 
-    def _fold(self, phase: str, dt: float, entered: bool) -> None:
-        # the lock covers cross-thread accumulation (loop + planner
-        # thread share one budget); push/pop frequency is batch-level
-        # unless arm attribution is on, where the cost is opted into
-        with self._lock:
-            self.totals[phase] = self.totals.get(phase, 0.0) + dt
-            if entered:
-                self.counts[phase] = self.counts.get(phase, 0) + 1
+    def add(self, phase: str, dt: float) -> None:
+        """One entry of ``dt`` seconds straight into this thread's
+        totals, outside the stack (the selector wrapper's ``loop.idle``)."""
+        tl = self._tls
+        if not hasattr(tl, "totals"):
+            self._join()
+        totals, counts = tl.totals, tl.counts
+        totals[phase] = totals.get(phase, 0.0) + dt
+        counts[phase] = counts.get(phase, 0) + 1
 
     # ----------------------------------------------------------- slow path
 
@@ -158,21 +210,31 @@ class WallBudget:
         ("", "") when it is outside every phase.  Safe from any thread."""
         return self._active.get(ident, ("", ""))
 
-    def snapshot(self) -> dict[str, float]:
+    def _sum(self, which: int) -> dict:
         with self._lock:
-            return dict(self.totals)
+            parts = [t[which] for t in self._threads]
+        out: dict = {}
+        for part in parts:
+            for k, v in part.copy().items():
+                out[k] = out.get(k, 0) + v
+        return out
+
+    def snapshot(self) -> dict[str, float]:
+        """Self seconds per phase, summed over threads."""
+        return self._sum(0)
 
     def snapshot_counts(self) -> dict[str, int]:
-        with self._lock:
-            return dict(self.counts)
+        """Entries per phase, summed over threads."""
+        return self._sum(1)
 
     def clear(self) -> None:
         with self._lock:
-            self.totals.clear()
-            self.counts.clear()
+            for totals, counts in self._threads:
+                totals.clear()
+                counts.clear()
 
     def __repr__(self) -> str:
-        return f"<WallBudget phases={len(self.totals)}>"
+        return f"<WallBudget phases={len(self.snapshot())}>"
 
 
 class _PhaseCtx:
@@ -189,6 +251,55 @@ class _PhaseCtx:
 
     def __exit__(self, *exc):
         self._budget.pop()
+
+
+#: event loop -> its WallBudget (weak: a closed loop takes its budget)
+_LOOP_BUDGETS: "weakref.WeakKeyDictionary[asyncio.AbstractEventLoop, WallBudget]" = (
+    weakref.WeakKeyDictionary()
+)
+
+
+def loop_budget() -> WallBudget:
+    """The :class:`WallBudget` of the running event loop, made on first
+    use; a fresh budget of its own outside any loop.
+
+    Every role built on one loop (the scheduler, the workers and the
+    clients of an in-process cluster) bills into this one budget, so its
+    per-thread stack gives true self time across roles.  On first use
+    the loop's selector is wrapped to add its waits to ``loop.idle``;
+    a loop without a selector (``uvloop``) has no ``loop.idle``."""
+    try:
+        loop = asyncio.get_running_loop()
+    except RuntimeError:
+        return WallBudget()
+    budget = _LOOP_BUDGETS.get(loop)
+    if budget is None:
+        budget = _LOOP_BUDGETS[loop] = WallBudget()
+        _time_selector(loop, budget)
+    return budget
+
+
+def _time_selector(loop: asyncio.AbstractEventLoop, budget: WallBudget) -> None:
+    """Bill the loop's selector waits to ``loop.idle``: straight into the
+    totals on the loop thread, at the bottom of its (then empty) phase
+    stack, and as a trace annotation while a profiler trace is active."""
+    selector = getattr(loop, "_selector", None)
+    if selector is None:
+        return
+    select = selector.select
+    clock = budget.clock
+
+    def timed_select(timeout=None):
+        ann = trace_annotation(LOOP_IDLE)
+        t0 = clock()
+        try:
+            return select(timeout)
+        finally:
+            budget.add(LOOP_IDLE, clock() - t0)
+            if ann is not None:
+                ann.__exit__(None, None, None)
+
+    selector.select = timed_select
 
 
 class ControlPlaneProfiler(Profiler):
@@ -504,10 +615,12 @@ def profile_to_speedscope(tree: dict, name: str = "dtpu-profile") -> dict:
 __all__ = [
     "ControlPlaneProfiler",
     "IDLE_FILES",
+    "LOOP_IDLE",
     "LoopWatchdog",
     "PHASES",
     "PHASE_PREFIX",
     "WallBudget",
+    "loop_budget",
     "merge",
     "profile_jsonl",
     "profile_records",

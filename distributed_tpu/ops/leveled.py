@@ -37,12 +37,15 @@ from __future__ import annotations
 import ctypes
 import functools
 import threading
+import time
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from distributed_tpu.tracing import TraceSpan
 
 INT32_MAX = np.int32(2**31 - 1)
 
@@ -739,6 +742,33 @@ def _apply_chunk(bufs, chunks, start):
     )
 
 
+class _Span(TraceSpan):
+    """One ``place.<phase>`` span of :func:`place_graph_streamed`: it
+    shows on any active profiler trace, and its seconds add to
+    ``timings["<phase>_s"]``."""
+
+    __slots__ = ("_timings", "_key", "_t0")
+
+    def __init__(self, name: str, timings: dict | None):
+        super().__init__(name)
+        self._timings = timings
+        self._key = name.split(".", 1)[1] + "_s"
+
+    def __enter__(self) -> "_Span":
+        super().__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._timings is not None:
+            self._timings[self._key] += time.perf_counter() - self._t0
+        super().__exit__(*exc)
+
+
+#: the ``timings`` keys of ``place_graph_streamed``'s spans
+_SPAN_KEYS = ("topo_s", "fill_wait_s", "upload_s", "dispatch_s", "finalize_s")
+
+
 def place_graph_streamed(
     durations,
     out_bytes,
@@ -793,6 +823,13 @@ def place_graph_streamed(
     :func:`place_graph_leveled_sharded`.  The sharded wire is always
     the exact f16 format (``compact`` is ignored).
 
+    ``timings``, when given, receives the wire ``fmt`` and the seconds
+    of the main thread's spans: ``topo_s`` (phase 1), ``fill_wait_s``
+    (waiting on the fill thread), ``upload_s`` (device buffers, chunk
+    encode, ``device_put`` + ``_apply_chunk``), ``dispatch_s`` (the
+    wave runs) and ``finalize_s`` (the last download and the unpack).
+    Each span shows as ``place.<phase>`` on any active profiler trace.
+
     Returns ``(packed, result)``; ``packed``'s host arrays are fully
     filled by return time.
     """
@@ -804,19 +841,18 @@ def place_graph_streamed(
     dst = np.ascontiguousarray(dst, np.int32)
     T = len(durations)
     E = len(src)
-    import time as _time
+    if timings is not None:
+        timings.update(dict.fromkeys(_SPAN_KEYS, 0.0))
 
     lib = native.load()
     if lib is None or T < min_stream:
-        t0 = _time.perf_counter()
-        packed = pack_graph(durations, out_bytes, src, dst,
-                            bandwidth=bandwidth, latency=latency)
+        # fallback path: the whole pack is serial, and place.topo holds
+        # it, so callers (bench.py) never read a zero pack phase
+        with _Span("place.topo", timings):
+            packed = pack_graph(durations, out_bytes, src, dst,
+                                bandwidth=bandwidth, latency=latency)
         if timings is not None:
-            # fallback path: the whole pack is serial — report it so
-            # callers (bench.py) never fabricate a zero pack phase
-            timings["topo_s"] = _time.perf_counter() - t0
             timings["fmt"] = "f16"
-            timings["fallback"] = True
         if mesh is not None:
             result = place_graph_leveled_sharded(
                 mesh, packed, nthreads, occupancy0, running,
@@ -825,72 +861,69 @@ def place_graph_streamed(
         else:
             result = place_graph_leveled(packed, nthreads, occupancy0,
                                          running)
-        if timings is not None:
-            timings["total_s"] = _time.perf_counter() - t0
         return packed, result
 
-    t0 = _time.perf_counter()
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    f32p = ctypes.POINTER(ctypes.c_float)
-    level = np.empty(T, np.int32)
-    perm = np.empty(T, np.int32)
-    offsets_buf = np.empty(T + 1, np.int32)  # pass writes [0, n_levels]
-    heavy = np.empty(T, np.int32)
-    heavy2 = np.empty(T, np.int32)
-    dep_total = np.empty(T, np.float32)
-    indeg = np.empty(T, np.int32)
-    inv = np.empty(T, np.int32)
-    n_levels = lib.graphpack_topo(
-        T, E,
-        out_bytes.ctypes.data_as(f32p),
-        src.ctypes.data_as(i32p), dst.ctypes.data_as(i32p),
-        level.ctypes.data_as(i32p), perm.ctypes.data_as(i32p),
-        offsets_buf.ctypes.data_as(i32p),
-        heavy.ctypes.data_as(i32p), heavy2.ctypes.data_as(i32p),
-        dep_total.ctypes.data_as(f32p), indeg.ctypes.data_as(i32p),
-        inv.ctypes.data_as(i32p),
-    )
-    if n_levels < 0:
-        raise ValueError("graph has a cycle")
-    offsets = offsets_buf[: n_levels + 1].copy()
+    # phase 1, the serial topology pass
+    with _Span("place.topo", timings):
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        f32p = ctypes.POINTER(ctypes.c_float)
+        level = np.empty(T, np.int32)
+        perm = np.empty(T, np.int32)
+        offsets_buf = np.empty(T + 1, np.int32)  # pass writes [0, n_levels]
+        heavy = np.empty(T, np.int32)
+        heavy2 = np.empty(T, np.int32)
+        dep_total = np.empty(T, np.float32)
+        indeg = np.empty(T, np.int32)
+        inv = np.empty(T, np.int32)
+        n_levels = lib.graphpack_topo(
+            T, E,
+            out_bytes.ctypes.data_as(f32p),
+            src.ctypes.data_as(i32p), dst.ctypes.data_as(i32p),
+            level.ctypes.data_as(i32p), perm.ctypes.data_as(i32p),
+            offsets_buf.ctypes.data_as(i32p),
+            heavy.ctypes.data_as(i32p), heavy2.ctypes.data_as(i32p),
+            dep_total.ctypes.data_as(f32p), indeg.ctypes.data_as(i32p),
+            inv.ctypes.data_as(i32p),
+        )
+        if n_levels < 0:
+            raise ValueError("graph has a cycle")
+        offsets = offsets_buf[: n_levels + 1].copy()
 
-    if mesh is not None:
-        n_shard = _mesh_shards(mesh)[2]
-        sharded_runs = _plan_runs_sharded(offsets, n_shard)
-        runs = [(Fl * n_shard, ws) for Fl, ws in sharded_runs]
-    else:
-        sharded_runs = None
-        runs = _plan_runs(offsets)
-    Tp = T + _compute_pad(T, runs, offsets)
-    Lp = _bucket(n_levels + 1, floor=64)
-    # host fill targets are Tp-sized with a zero tail so chunk windows
-    # (fixed length C, clamped into [0, Tp)) always slice cleanly; only
-    # the tail needs zeroing — the fill chunks cover every row in [0, T)
-    # and np.zeros over six 1M-row arrays costs real milliseconds of the
-    # serial phase on a one-core host.  The SHARDED dispatch assembles
-    # run tiles straight from these host arrays, and a tile window can
-    # overread rows the filler has not reached yet (bucket overshoot
-    # into the next wave) — those lanes are validity-masked on device
-    # but must not be garbage (NaN-free gathers), so the mesh path
-    # zero-fills everything up front.
-    dur_s = np.empty(Tp, np.float32)
-    heavy_s = np.empty(Tp, np.int32)
-    heavy2_s = np.empty(Tp, np.int32)
-    xp_s = np.empty(Tp, np.float32)
-    xp2_s = np.empty(Tp, np.float32)
-    xa_s = np.empty(Tp, np.float32)
-    for _buf in (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s):
         if mesh is not None:
-            _buf[:] = 0
+            n_shard = _mesh_shards(mesh)[2]
+            sharded_runs = _plan_runs_sharded(offsets, n_shard)
+            runs = [(Fl * n_shard, ws) for Fl, ws in sharded_runs]
         else:
-            _buf[T:] = 0
-    packed = PackedGraph(
-        perm=perm, level=level, offsets=offsets, n_levels=int(n_levels),
-        duration_s=dur_s[:T], heavy_s=heavy_s[:T], heavy2_s=heavy2_s[:T],
-        xfer_pref_s=xp_s[:T], xfer_pref2_s=xp2_s[:T], xfer_all_s=xa_s[:T],
-    )
-    if timings is not None:
-        timings["topo_s"] = _time.perf_counter() - t0
+            sharded_runs = None
+            runs = _plan_runs(offsets)
+        Tp = T + _compute_pad(T, runs, offsets)
+        Lp = _bucket(n_levels + 1, floor=64)
+        # host fill targets are Tp-sized with a zero tail so chunk windows
+        # (fixed length C, clamped into [0, Tp)) always slice cleanly; only
+        # the tail needs zeroing — the fill chunks cover every row in [0, T)
+        # and np.zeros over six 1M-row arrays costs real milliseconds of the
+        # serial phase on a one-core host.  The SHARDED dispatch assembles
+        # run tiles straight from these host arrays, and a tile window can
+        # overread rows the filler has not reached yet (bucket overshoot
+        # into the next wave) — those lanes are validity-masked on device
+        # but must not be garbage (NaN-free gathers), so the mesh path
+        # zero-fills everything up front.
+        dur_s = np.empty(Tp, np.float32)
+        heavy_s = np.empty(Tp, np.int32)
+        heavy2_s = np.empty(Tp, np.int32)
+        xp_s = np.empty(Tp, np.float32)
+        xp2_s = np.empty(Tp, np.float32)
+        xa_s = np.empty(Tp, np.float32)
+        for _buf in (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s):
+            if mesh is not None:
+                _buf[:] = 0
+            else:
+                _buf[T:] = 0
+        packed = PackedGraph(
+            perm=perm, level=level, offsets=offsets, n_levels=int(n_levels),
+            duration_s=dur_s[:T], heavy_s=heavy_s[:T], heavy2_s=heavy2_s[:T],
+            xfer_pref_s=xp_s[:T], xfer_pref2_s=xp2_s[:T], xfer_all_s=xa_s[:T],
+        )
 
     wide, uniform, thr_h, run_h, occ_h = _worker_params(
         nthreads, occupancy0, running
@@ -902,20 +935,21 @@ def place_graph_streamed(
         timings["fmt"] = fmt
 
     C = min(chunk_rows, T)
-    if mesh is not None:
-        bufs = None  # sharded runs ship per-run tiles, no chunk buffers
-    elif fmt == "packed":
-        bufs = (
-            jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.int32),
-            jnp.zeros(Tp, jnp.uint16), jnp.zeros(Tp, jnp.uint8),
-            jnp.zeros(Tp, jnp.uint8), jnp.zeros(Tp, jnp.uint8),
-        )
-    else:
-        bufs = (
-            jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.int32),
-            jnp.zeros(Tp, jnp.int32), jnp.zeros(Tp, jnp.float16),
-            jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.float16),
-        )
+    with _Span("place.upload", timings):
+        if mesh is not None:
+            bufs = None  # sharded runs ship per-run tiles, no chunk buffers
+        elif fmt == "packed":
+            bufs = (
+                jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.int32),
+                jnp.zeros(Tp, jnp.uint16), jnp.zeros(Tp, jnp.uint8),
+                jnp.zeros(Tp, jnp.uint8), jnp.zeros(Tp, jnp.uint8),
+            )
+        else:
+            bufs = (
+                jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.int32),
+                jnp.zeros(Tp, jnp.int32), jnp.zeros(Tp, jnp.float16),
+                jnp.zeros(Tp, jnp.float16), jnp.zeros(Tp, jnp.float16),
+            )
 
     boundaries = [(i0, min(i0 + C, T)) for i0 in range(0, T, C)]
     done = [threading.Event() for _ in boundaries]
@@ -948,43 +982,47 @@ def place_graph_streamed(
     th = threading.Thread(target=filler, name="graphpack-fill", daemon=True)
     th.start()
 
-    if mesh is not None:
-        rs: _RunState = _ShardedRunState(
-            mesh, packed, Tp, Lp, wide, uniform, thr_h, run_h, occ_h,
-            fleet_dev=fleet_dev, stats=stats,
-        )
-        host_fill = (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s)
-    else:
-        rs = _RunState(packed, Tp, Lp, wide, uniform,
-                       jnp.asarray(thr_h), jnp.asarray(run_h),
-                       jnp.asarray(occ_h), fmt=fmt)
+    with _Span("place.upload", timings):
+        if mesh is not None:
+            rs: _RunState = _ShardedRunState(
+                mesh, packed, Tp, Lp, wide, uniform, thr_h, run_h, occ_h,
+                fleet_dev=fleet_dev, stats=stats,
+            )
+            host_fill = (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s)
+        else:
+            rs = _RunState(packed, Tp, Lp, wide, uniform,
+                           jnp.asarray(thr_h), jnp.asarray(run_h),
+                           jnp.asarray(occ_h), fmt=fmt)
     run_i = 0
     for (i0, i1), evt in zip(boundaries, done):
-        evt.wait()
+        with _Span("place.fill_wait", timings):
+            evt.wait()
         if fill_err:
             raise RuntimeError("graph pack fill failed") from fill_err[0]
         if mesh is None:
-            # fixed-length window clamped into the buffers: the last
-            # chunk re-sends a few already-final rows instead of
-            # changing shape (one compiled _apply_chunk per length)
-            start = min(i0, Tp - C)
-            sl = slice(start, start + C)
-            if fmt == "packed":
-                lo, hi = _enc_heavy_pair(heavy_s[sl], heavy2_s[sl])
-                host = (
-                    dur_s[sl].astype(np.float16), lo, hi,
-                    _enc_cost(xp_s[sl]), _enc_cost(xp2_s[sl]),
-                    _enc_cost(xa_s[sl]),
-                )
-            else:
-                host = (
-                    dur_s[sl].astype(np.float16),
-                    heavy_s[sl], heavy2_s[sl],
-                    xp_s[sl].astype(np.float16),
-                    xp2_s[sl].astype(np.float16),
-                    xa_s[sl].astype(np.float16),
-                )
-            bufs = _apply_chunk(bufs, jax.device_put(host), jnp.int32(start))
+            with _Span("place.upload", timings):
+                # fixed-length window clamped into the buffers: the last
+                # chunk re-sends a few already-final rows instead of
+                # changing shape (one compiled _apply_chunk per length)
+                start = min(i0, Tp - C)
+                sl = slice(start, start + C)
+                if fmt == "packed":
+                    lo, hi = _enc_heavy_pair(heavy_s[sl], heavy2_s[sl])
+                    host = (
+                        dur_s[sl].astype(np.float16), lo, hi,
+                        _enc_cost(xp_s[sl]), _enc_cost(xp2_s[sl]),
+                        _enc_cost(xa_s[sl]),
+                    )
+                else:
+                    host = (
+                        dur_s[sl].astype(np.float16),
+                        heavy_s[sl], heavy2_s[sl],
+                        xp_s[sl].astype(np.float16),
+                        xp2_s[sl].astype(np.float16),
+                        xa_s[sl].astype(np.float16),
+                    )
+                bufs = _apply_chunk(bufs, jax.device_put(host),
+                                    jnp.int32(start))
         # dispatch every fused run whose rows have fully landed; its
         # windows may read a few rows past i1 — still the zero fill,
         # masked by the wave's validity lanes
@@ -992,25 +1030,25 @@ def place_graph_streamed(
             run_i < len(runs)
             and int(offsets[runs[run_i][1][-1] + 1]) <= i1
         ):
-            if mesh is not None:
-                # sharded: assemble [K, F] tiles from the host fill
-                # arrays and ship each shard exactly its slice — the
-                # async per-shard H2D overlaps both the pack fill and
-                # the earlier runs' compute
-                Fl, waves = sharded_runs[run_i]
-                rs.dispatch(host_fill, Fl, waves,
-                            last=run_i == len(runs) - 1)
-            else:
-                F, waves = runs[run_i]
-                rs.dispatch(bufs, F, waves, last=run_i == len(runs) - 1)
+            last = run_i == len(runs) - 1
+            with _Span("place.dispatch", timings):
+                if mesh is not None:
+                    # sharded: assemble [K, F] tiles from the host fill
+                    # arrays and ship each shard exactly its slice — the
+                    # async per-shard H2D overlaps both the pack fill
+                    # and the earlier runs' compute
+                    Fl, waves = sharded_runs[run_i]
+                    rs.dispatch(host_fill, Fl, waves, last=last)
+                else:
+                    F, waves = runs[run_i]
+                    rs.dispatch(bufs, F, waves, last=last)
             run_i += 1
-    th.join()
-    assert run_i == len(runs), "not all runs dispatched"
-    if mesh is not None:
-        rs.record_shard_ms()
-    result = rs.finalize()
-    if timings is not None:
-        timings["total_s"] = _time.perf_counter() - t0
+    with _Span("place.finalize", timings):
+        th.join()
+        assert run_i == len(runs), "not all runs dispatched"
+        if mesh is not None:
+            rs.record_shard_ms()
+        result = rs.finalize()
     return packed, result
 
 

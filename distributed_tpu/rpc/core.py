@@ -190,6 +190,10 @@ class Server:
     # their config prefix; CLI --preload flags are handled by the CLIs
     # and ADD to these
     preload_config_prefix: str | None = None
+    #: a server with both bills each stream payload's decode and
+    #: dispatch to ``stream_phase`` of the WallBudget ``wall``
+    wall: Any = None
+    stream_phase: str | None = None
 
     def __init__(
         self,
@@ -442,90 +446,121 @@ class Server:
                 await comm.close()
 
     async def handle_stream(self, comm: Comm, extra: dict | None = None) -> None:
-        """Serve one-way batched-stream ops (reference core.py:1015)."""
+        """Serve one-way batched-stream ops (reference core.py:1015).
+
+        Each payload's decode and dispatch bills ``stream_phase`` to the
+        server's wall budget, where it has both.  The phase is left before
+        every ``await`` and entered again after it: no phase spans one."""
         extra = extra or {}
         closed = False
+        phase = self.stream_phase
+        wall = self.wall if phase else None
+
+        async def unbilled(result: Any) -> None:
+            if wall is None:
+                await result
+                return
+            wall.pop()
+            try:
+                await result
+            finally:
+                wall.push(phase)
+
         try:
             while not closed:
                 msgs = await comm.read()
                 if not isinstance(msgs, (tuple, list)):
                     msgs = (msgs,)
-                i, n = 0, len(msgs)
-                while i < n:
-                    msg = msgs[i]
-                    if msg == "OK":  # initial handshake ack
-                        i += 1
-                        continue
-                    op = msg.pop("op", None)
-                    if op is None:
-                        raise ValueError(f"stream message without op: {msg!r}")
-                    if op == "close-stream":
-                        closed = True
-                        break
-                    batch_handler = self.stream_batch_handlers.get(op)
-                    if batch_handler is not None:
-                        # fold the whole consecutive same-op run (a
-                        # task-finished flood, a free/release flood) into
-                        # ONE dispatch: the handler sees the run as a
-                        # list and drives the state machine in a single
-                        # batched pass instead of once per message
-                        j = i + 1
-                        while (
-                            j < n
-                            and isinstance(msgs[j], dict)
-                            and msgs[j].get("op") == op
-                        ):
-                            msgs[j].pop("op", None)
-                            j += 1
-                        batch = list(msgs[i:j])
-                        i = j
-                        # causal stimulus ids are minted AT INGRESS: any
-                        # message folding into a batched engine pass
-                        # without one (client-plane floods; worker
-                        # messages always carry theirs) gets a fresh id
-                        # here, so the flight recorder can join the
-                        # inbound flood to the engine pass, the
-                        # transitions it produced, and the envelopes
-                        # those emitted (docs/observability.md)
-                        for m in batch:
-                            if not m.get("stimulus_id"):
-                                m["stimulus_id"] = seq_name(f"igr-{op}")
-                        try:
-                            result = batch_handler(batch, **extra)
-                            if result is not None and inspect.isawaitable(result):
-                                await result
-                        except Exception:
-                            logger.exception(
-                                "stream batch handler %r failed", op
-                            )
-                        continue
-                    i += 1
-                    handler = self.stream_handlers.get(op)
-                    if handler is None:
-                        logger.error("unknown stream op %r", op)
-                        continue
-                    try:
-                        # stream context (worker=/client= address) fills in
-                        # unless the message already carries the field
-                        result = handler(**{**extra, **msg}) if extra else handler(**msg)
-                        if result is not None and inspect.isawaitable(result):
-                            await result
-                    except Exception:
-                        logger.exception("stream handler %r failed", op)
-                # payload boundary: servers that coalesce stream stimuli
-                # (the worker's event buffer) flush here, SYNCHRONOUSLY,
-                # so a whole batched payload becomes one state-machine
-                # batch and no locally-generated event can interleave
-                flush = getattr(self, "stream_payload_flush", None)
-                if flush is not None:
-                    try:
-                        flush()
-                    except Exception:
-                        logger.exception("stream payload flush failed")
+                if wall is not None:
+                    wall.push(phase)
+                try:
+                    closed = await self._dispatch_payload(msgs, extra, unbilled)
+                finally:
+                    if wall is not None:
+                        wall.pop()
         except CommClosedError:
             pass
         finally:
             await comm.close()
+
+    async def _dispatch_payload(self, msgs, extra: dict, unbilled) -> bool:
+        """One stream payload through the handlers; True at close-stream.
+        ``unbilled(result)`` awaits a handler's awaitable outside the
+        stream phase."""
+        closed = False
+        i, n = 0, len(msgs)
+        while i < n:
+            msg = msgs[i]
+            if msg == "OK":  # initial handshake ack
+                i += 1
+                continue
+            op = msg.pop("op", None)
+            if op is None:
+                raise ValueError(f"stream message without op: {msg!r}")
+            if op == "close-stream":
+                closed = True
+                break
+            batch_handler = self.stream_batch_handlers.get(op)
+            if batch_handler is not None:
+                # fold the whole consecutive same-op run (a
+                # task-finished flood, a free/release flood) into
+                # ONE dispatch: the handler sees the run as a
+                # list and drives the state machine in a single
+                # batched pass instead of once per message
+                j = i + 1
+                while (
+                    j < n
+                    and isinstance(msgs[j], dict)
+                    and msgs[j].get("op") == op
+                ):
+                    msgs[j].pop("op", None)
+                    j += 1
+                batch = list(msgs[i:j])
+                i = j
+                # causal stimulus ids are minted AT INGRESS: any
+                # message folding into a batched engine pass
+                # without one (client-plane floods; worker
+                # messages always carry theirs) gets a fresh id
+                # here, so the flight recorder can join the
+                # inbound flood to the engine pass, the
+                # transitions it produced, and the envelopes
+                # those emitted (docs/observability.md)
+                for m in batch:
+                    if not m.get("stimulus_id"):
+                        m["stimulus_id"] = seq_name(f"igr-{op}")
+                try:
+                    result = batch_handler(batch, **extra)
+                    if result is not None and inspect.isawaitable(result):
+                        await unbilled(result)
+                except Exception:
+                    logger.exception(
+                        "stream batch handler %r failed", op
+                    )
+                continue
+            i += 1
+            handler = self.stream_handlers.get(op)
+            if handler is None:
+                logger.error("unknown stream op %r", op)
+                continue
+            try:
+                # stream context (worker=/client= address) fills in
+                # unless the message already carries the field
+                result = handler(**{**extra, **msg}) if extra else handler(**msg)
+                if result is not None and inspect.isawaitable(result):
+                    await unbilled(result)
+            except Exception:
+                logger.exception("stream handler %r failed", op)
+        # payload boundary: servers that coalesce stream stimuli
+        # (the worker's event buffer) flush here, SYNCHRONOUSLY,
+        # so a whole batched payload becomes one state-machine
+        # batch and no locally-generated event can interleave
+        flush = getattr(self, "stream_payload_flush", None)
+        if flush is not None:
+            try:
+                flush()
+            except Exception:
+                logger.exception("stream payload flush failed")
+        return closed
 
     # ------------------------------------------------------------- helpers
 

@@ -109,6 +109,11 @@ class ActiveMemoryManagerExtension:
     # ------------------------------------------------------------ one round
 
     def run_once(self) -> None:
+        """One round of every policy, billed to ``amm.cycle``."""
+        with self.state.wall.phase("amm.cycle"):
+            self._run_round()
+
+    def _run_round(self) -> None:
         stimulus_id = self.seq("amm")
         # projected memory per worker for this round: actual managed
         # bytes plus/minus the round's own decisions (reference

@@ -540,58 +540,12 @@ class JaxPlacement:
         the device plan to the ``update-graph`` ingress that caused it."""
         if not self.enabled:
             return 0
-        # drop stale hints first: keys gone from the scheduler or no
-        # longer pending will never be consulted and would accumulate
-        if self.plan:
-            before = len(self.plan)
-            self.plan = {
-                k: a
-                for k, a in self.plan.items()
-                if (pts := state.tasks.get(k)) is not None
-                and pts.state in ("released", "waiting", "queued", "no-worker")
-            }
-            self.hint_drops["stale-dropped"] += before - len(self.plan)
-        # plan only runnable *pending* tasks whose dependencies are inside
-        # the batch (external deps already sit on specific workers: the
-        # python locality oracle is the right tool for those few).
-        # Rootish tasks ARE planned: the partitioner co-assigns a tile's
-        # sources with the tile, so inputs are born where they are
-        # consumed instead of round-robined by rootish co-assignment.
-        batch: list[TaskState] = []
-        keyset = set(tasks)
-        for ts in tasks.values():
-            if ts.run_spec is None or ts.actor or ts.has_restrictions:
-                continue
-            if ts.state not in ("released", "waiting"):
-                continue
-            if all(dts.key in keyset for dts in ts.dependencies):
-                batch.append(ts)
-        if len(batch) < self.min_batch or len(batch) > self.max_batch:
+        # the batch filter and snapshot run on the loop: plan.snapshot
+        with state.wall.phase("plan.snapshot", stimulus_id):
+            planned = self._plan_snapshot(state, tasks)
+        if planned is None:
             return 0
-        if len(state.workers) < max(self.min_workers, 2):
-            return 0
-        # PRIORITY order is load-bearing: the partitioner's block init
-        # chunks this axis, and scheduler priorities are depth-first
-        # graph order (graph/order.py) — adjacent tasks are related
-        batch.sort(key=lambda ts: ts.priority or (0,))
-        durations, out_bytes, known_frac = self._snapshot_nodes(state, batch)
-        ratio = self.min_transfer_ratio
-        if (
-            ratio
-            and known_frac >= 0.5
-            and float(out_bytes.mean()) / state.bandwidth
-            + state.transfer_latency
-            < ratio * float(durations.mean())
-        ):
-            # transfers are noise next to compute: locality hints cannot
-            # pay for themselves on this graph (and occupancy-aware
-            # consumption would discard them anyway) — skip the dispatch
-            # before paying for the edge snapshot.  Only trustworthy
-            # when durations are mostly MEASURED: the 500ms unknown-task
-            # default would otherwise veto planning for every
-            # first-of-its-kind graph exactly when the plan matters.
-            return 0
-        snapshot = self._snapshot(state, batch, durations, out_bytes)
+        batch, snapshot = planned
         state.trace.emit(
             "kernel", "placement-plan", stimulus_id, n=len(batch)
         )
@@ -671,6 +625,63 @@ class JaxPlacement:
         fut.add_done_callback(_done)
         return 0
 
+    def _plan_snapshot(self, state: "SchedulerState",
+                       tasks: "dict[Key, TaskState]"):
+        """``(batch, snapshot)`` of the tasks to plan, or None when this
+        graph is not worth a device plan."""
+        # drop stale hints first: keys gone from the scheduler or no
+        # longer pending will never be consulted and would accumulate
+        if self.plan:
+            before = len(self.plan)
+            self.plan = {
+                k: a
+                for k, a in self.plan.items()
+                if (pts := state.tasks.get(k)) is not None
+                and pts.state in ("released", "waiting", "queued", "no-worker")
+            }
+            self.hint_drops["stale-dropped"] += before - len(self.plan)
+        # plan only runnable *pending* tasks whose dependencies are inside
+        # the batch (external deps already sit on specific workers: the
+        # python locality oracle is the right tool for those few).
+        # Rootish tasks ARE planned: the partitioner co-assigns a tile's
+        # sources with the tile, so inputs are born where they are
+        # consumed instead of round-robined by rootish co-assignment.
+        batch: list[TaskState] = []
+        keyset = set(tasks)
+        for ts in tasks.values():
+            if ts.run_spec is None or ts.actor or ts.has_restrictions:
+                continue
+            if ts.state not in ("released", "waiting"):
+                continue
+            if all(dts.key in keyset for dts in ts.dependencies):
+                batch.append(ts)
+        if len(batch) < self.min_batch or len(batch) > self.max_batch:
+            return None
+        if len(state.workers) < max(self.min_workers, 2):
+            return None
+        # PRIORITY order is load-bearing: the partitioner's block init
+        # chunks this axis, and scheduler priorities are depth-first
+        # graph order (graph/order.py) — adjacent tasks are related
+        batch.sort(key=lambda ts: ts.priority or (0,))
+        durations, out_bytes, known_frac = self._snapshot_nodes(state, batch)
+        ratio = self.min_transfer_ratio
+        if (
+            ratio
+            and known_frac >= 0.5
+            and float(out_bytes.mean()) / state.bandwidth
+            + state.transfer_latency
+            < ratio * float(durations.mean())
+        ):
+            # transfers are noise next to compute: locality hints cannot
+            # pay for themselves on this graph (and occupancy-aware
+            # consumption would discard them anyway) — skip the dispatch
+            # before paying for the edge snapshot.  Only trustworthy
+            # when durations are mostly MEASURED: the 500ms unknown-task
+            # default would otherwise veto planning for every
+            # first-of-its-kind graph exactly when the plan matters.
+            return None
+        return batch, self._snapshot(state, batch, durations, out_bytes)
+
     def planner_ident(self) -> int | None:
         """Thread ident of the daemon planner thread (None before the
         first async plan spawns it) — the control-plane profiler
@@ -696,26 +707,27 @@ class JaxPlacement:
         tasks still pending — tasks the oracle placed while the plan was
         computing would otherwise accumulate as dead entries forever
         (and, with reused pure keys, serve stale hints to later graphs)."""
-        self.plans_inflight -= 1
-        plan, engine_shards = plan_shards or (None, None)
-        if engine_shards:
-            state.observe_engine_shards(engine_shards)
-        if plan:
-            live = {
-                k: v
-                for k, v in plan.items()
-                if (ts := state.tasks.get(k)) is not None
-                and ts.state in ("released", "waiting", "queued", "no-worker")
-            }
-            self.hint_drops["landed-late"] += len(plan) - len(live)
-            if live:
-                self.plan.update(live)
-                self.plan_stim = stimulus_id
-                self.plans_computed += 1
-                logger.debug(
-                    "planned %d tasks on device (%d already placed)",
-                    len(live), len(plan) - len(live),
-                )
+        with state.wall.phase("plan.merge", stimulus_id):
+            self.plans_inflight -= 1
+            plan, engine_shards = plan_shards or (None, None)
+            if engine_shards:
+                state.observe_engine_shards(engine_shards)
+            if plan:
+                live = {
+                    k: v
+                    for k, v in plan.items()
+                    if (ts := state.tasks.get(k)) is not None
+                    and ts.state in ("released", "waiting", "queued", "no-worker")
+                }
+                self.hint_drops["landed-late"] += len(plan) - len(live)
+                if live:
+                    self.plan.update(live)
+                    self.plan_stim = stimulus_id
+                    self.plans_computed += 1
+                    logger.debug(
+                        "planned %d tasks on device (%d already placed)",
+                        len(live), len(plan) - len(live),
+                    )
 
     @staticmethod
     def _snapshot_nodes(state: "SchedulerState", batch: list):

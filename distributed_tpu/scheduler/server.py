@@ -16,6 +16,7 @@ from typing import Any, Iterable
 
 from distributed_tpu import config
 from distributed_tpu.comm.core import Comm
+from distributed_tpu.diagnostics.selfprofile import loop_budget
 from distributed_tpu.exceptions import CommClosedError
 from distributed_tpu.graph.spec import Key
 from distributed_tpu.protocol.serialize import (
@@ -121,6 +122,7 @@ class Scheduler(Server):
 
     default_port = 8786
     preload_config_prefix = "scheduler"
+    stream_phase = "ingest.stream"
 
     def __init__(
         self,
@@ -151,10 +153,14 @@ class Scheduler(Server):
             placement = JaxPlacement()
         elif placement is False:
             placement = None
+        # one wall budget per event loop (diagnostics/selfprofile.py):
+        # shared with every worker and client built on this loop
+        self.wall = loop_budget()
         self.state = SchedulerState(
             validate=validate,
             transition_counter_max=transition_counter_max,
             placement=placement,
+            wall=self.wall,
         )
         self.generation = 0
         # address -> BatchedSend for workers; client key -> BatchedSend
@@ -1190,39 +1196,41 @@ class Scheduler(Server):
     ) -> None:
         """Receive a task graph from a client (reference scheduler.py:4662)."""
         stimulus_id = stimulus_id or seq_name("update-graph")
-        try:
-            tasks = unwrap(tasks) or {}
-            self._trace_ingress("update-graph", len(tasks), stimulus_id)
-            deps = {
-                k: set(v) for k, v in (dependencies or {}).items()
-            }
-            self.generation += 1
-            client_msgs, worker_msgs = self.state.update_graph_core(
-                tasks,
-                deps,
-                list(keys),
-                client=client,
-                priorities=priorities,
-                user_priority=user_priority,
-                generation=self.generation,
-                annotations_by_key=annotations_by_key,
-                retries=retries,
-                actors=actors,
-                stimulus_id=stimulus_id,
-            )
-            self.send_all(client_msgs, worker_msgs)
-        except Exception as e:
-            logger.exception("update_graph failed")
-            for key in keys:
-                self.report(
-                    {
-                        "op": "task-erred",
-                        "key": key,
-                        "exception": Serialize(e),
-                        "traceback": None,
-                    },
+        # no await inside: the phase never spans one
+        with self.state.wall.phase("ingest.graph", stimulus_id):
+            try:
+                tasks = unwrap(tasks) or {}
+                self._trace_ingress("update-graph", len(tasks), stimulus_id)
+                deps = {
+                    k: set(v) for k, v in (dependencies or {}).items()
+                }
+                self.generation += 1
+                client_msgs, worker_msgs = self.state.update_graph_core(
+                    tasks,
+                    deps,
+                    list(keys),
                     client=client,
+                    priorities=priorities,
+                    user_priority=user_priority,
+                    generation=self.generation,
+                    annotations_by_key=annotations_by_key,
+                    retries=retries,
+                    actors=actors,
+                    stimulus_id=stimulus_id,
                 )
+                self.send_all(client_msgs, worker_msgs)
+            except Exception as e:
+                logger.exception("update_graph failed")
+                for key in keys:
+                    self.report(
+                        {
+                            "op": "task-erred",
+                            "key": key,
+                            "exception": Serialize(e),
+                            "traceback": None,
+                        },
+                        client=client,
+                    )
 
     def handle_client_desires_keys(self, keys: Iterable[Key] = (),
                                    client: str = "", **kw: Any) -> None:

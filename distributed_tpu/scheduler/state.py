@@ -675,6 +675,7 @@ class SchedulerState:
         placement: Any | None = None,
         mirror: bool | None = None,
         clock: Callable[[], float] | None = None,
+        wall: WallBudget | None = None,
     ):
         # injectable clock (ROADMAP item 1 simulator): every timestamp
         # this engine writes — transition-log rows, event stamps,
@@ -692,8 +693,10 @@ class SchedulerState:
         # docs/observability.md "Self-profiling"): exact monotonic
         # accumulators entered at the hot-path seams.  Always REAL
         # monotonic time, even under the simulator's virtual clock —
-        # the budget measures python cost, not simulated time.
-        self.wall = WallBudget()
+        # the budget measures python cost, not simulated time.  The
+        # Scheduler passes its event loop's budget (selfprofile.
+        # loop_budget), shared with every role on that loop.
+        self.wall = wall if wall is not None else WallBudget()
         # per-transition-arm attribution (engine.scalar-arm:<s>,<f>):
         # opt-in — two monotonic reads per transition are not free on
         # the flood path, so sim.profile_run turns it on explicitly

@@ -472,18 +472,20 @@ class WorkStealing:
 
     def balance(self) -> None:
         """One stealing cycle (reference stealing.py:402)."""
-        rr0 = self._rr
-        self._balance_cycle()
-        if self._rr != rr0 and self.state.trace.journal_enabled:
-            # the dep-free round-robin cursor advanced this cycle — and
-            # not every advance pairs with a journaled steal-request (a
-            # candidate can fail _steal_pays after the rotation).  The
-            # cursor picks future thieves, so a durable tail must pin it
-            # or a restored scheduler's next balance diverges from the
-            # unbounced twin (scheduler/durability.py).
-            self.state.trace.record(
-                "steal-rr", {"rr": self._rr}, self.seq("steal-rr")
-            )
+        with self.state.wall.phase("steal.cycle"):
+            rr0 = self._rr
+            self._balance_cycle()
+            if self._rr != rr0 and self.state.trace.journal_enabled:
+                # the dep-free round-robin cursor advanced this cycle —
+                # and not every advance pairs with a journaled
+                # steal-request (a candidate can fail _steal_pays after
+                # the rotation).  The cursor picks future thieves, so a
+                # durable tail must pin it or a restored scheduler's
+                # next balance diverges from the unbounced twin
+                # (scheduler/durability.py).
+                self.state.trace.record(
+                    "steal-rr", {"rr": self._rr}, self.seq("steal-rr")
+                )
 
     def _balance_cycle(self) -> None:
         self._last_balance = self.clock()

@@ -28,17 +28,74 @@ smoke stays under 5%.
 This file is pure (no IO, no event loop, no threads): the sans-io
 engines may import it, and the monotonic-time lint covers it — every
 timestamp here is ``utils.misc.time`` (monotonic).
+
+:func:`trace_annotation` is the one bridge to the XLA profiler: the
+program's named spans (wall-budget phases, ``ops/leveled.py``'s
+``place.*`` spans, worker task keys) show on any ``jax.profiler`` trace,
+stamped by the profiler on the device trace's clock.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 from bisect import bisect_left
 from collections import deque
 from typing import Any, Iterable
 
 from distributed_tpu import config
 from distributed_tpu.utils import time
+
+#: ``jax.profiler.TraceAnnotation`` once jax is imported (see
+#: :func:`trace_annotation`)
+_TraceAnnotation: Any = None
+
+
+def trace_active() -> bool:
+    """Whether a ``jax.profiler`` trace is active in this process: the
+    gate ``TraceAnnotation.is_enabled()`` (~20 ns).  jax is never
+    imported here: before anything imports it no trace can be running."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        if "jax.profiler" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        cls = _TraceAnnotation = TraceAnnotation
+    return cls.is_enabled()
+
+
+def trace_annotation(name: str) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name`` while a
+    profiler trace is active (:func:`trace_active`), else ``None``; the
+    caller exits it.  Spans cost nothing measurable with tracing off."""
+    if not trace_active():
+        return None
+    ann = _TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class TraceSpan:
+    """``with TraceSpan(name): ...`` — :func:`trace_annotation` as a
+    context manager: the span shows on a profiler trace, if one is
+    active when it is entered."""
+
+    __slots__ = ("name", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._ann: Any = None
+
+    def __enter__(self) -> "TraceSpan":
+        self._ann = trace_annotation(self.name)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 #: bump when a field is added/renamed/retyped; every JSONL record and
 #: journal record carries it as ``v`` (docs/observability.md)

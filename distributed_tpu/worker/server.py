@@ -22,6 +22,7 @@ from typing import Any
 from distributed_tpu import config
 from distributed_tpu.comm.core import Comm, connect
 from distributed_tpu.diagnostics import device_profile
+from distributed_tpu.diagnostics.selfprofile import loop_budget
 from distributed_tpu.exceptions import CommClosedError, Reschedule, WorkerClosedError
 from distributed_tpu.graph.spec import Key
 from distributed_tpu.protocol.serialize import Serialize, unwrap
@@ -70,6 +71,7 @@ class Worker(Server):
 
     blocked_handlers_config_key = "worker.blocked-handlers"
     preload_config_prefix = "worker"
+    stream_phase = "wshell.stream"
 
     def __init__(
         self,
@@ -135,6 +137,9 @@ class Worker(Server):
                     "spill", None, "", label, unit, value
                 ),
             )
+        # one wall budget per event loop (diagnostics/selfprofile.py):
+        # on an in-process cluster, shared with the scheduler and clients
+        self.wall = loop_budget()
         self.state = WorkerState(
             nthreads=self.nthreads,
             # config fallback mirrors the reference's worker.resources
@@ -151,6 +156,7 @@ class Worker(Server):
             execute_pipeline_threshold=config.parse_timedelta(
                 config.get("worker.execute-pipeline-threshold") or "5ms"
             ),
+            wall=self.wall,
         )
         self.data = self.state.data
         # unique prefix per worker: the statistical profiler samples by
@@ -1113,8 +1119,11 @@ class Worker(Server):
         (reference worker.py:1931)."""
         if self.status in (Status.closed, Status.failed):
             return
-        instructions = self.state.handle_stimulus(*events)
-        self._handle_instructions(instructions)
+        with self.state.wall.phase(
+            "wshell.instructions", events[0].stimulus_id if events else ""
+        ):
+            instructions = self.state.handle_stimulus(*events)
+            self._handle_instructions(instructions)
 
     def _handle_instructions(self, instructions: list[Instruction]) -> None:
         executes: list[Execute] = []
@@ -1322,10 +1331,8 @@ class Worker(Server):
                         set_thread_worker(self, _key)
                         t0 = _perf()
                         try:
-                            if device_profile.active():
-                                with device_profile.annotate(_key):
-                                    return fn(*args, **kwargs)
-                            return fn(*args, **kwargs)
+                            with device_profile.annotate(_key):
+                                return fn(*args, **kwargs)
                         finally:
                             self._note_inner_duration(_pre, _perf() - t0)
 
@@ -1451,13 +1458,11 @@ class Worker(Server):
                         set_thread_worker(self, key)
                         t0 = _perf()
                         try:
-                            if device_profile.active():
-                                # device trace running: mark this task's
-                                # span on the XLA timeline so its device
-                                # ops group under the task key
-                                with device_profile.annotate(key):
-                                    return fn(*args, **kwargs)
-                            return fn(*args, **kwargs)
+                            # a profiler trace running: this task's span
+                            # on the XLA timeline, so its device ops
+                            # group under the task key
+                            with device_profile.annotate(key):
+                                return fn(*args, **kwargs)
                         finally:
                             self._note_inner_duration(_pre, _perf() - t0)
 

@@ -427,6 +427,7 @@ class WorkerState:
         execute_pipeline: int = 0,
         execute_pipeline_threshold: float = 0.005,
         clock: Callable[[], float] | None = None,
+        wall: WallBudget | None = None,
     ):
         self.address = address
         self.nthreads = nthreads
@@ -492,8 +493,9 @@ class WorkerState:
         # ``wengine.stimulus`` per handle_stimulus batch, plus opt-in
         # ``wengine.scalar-arm:<start>,<finish>`` arms — always REAL
         # monotonic time (python cost, not virtual time), so the
-        # injectable clock above deliberately does not re-point it
-        self.wall = WallBudget()
+        # injectable clock above deliberately does not re-point it.  The
+        # Worker passes its event loop's budget (selfprofile.loop_budget)
+        self.wall = wall if wall is not None else WallBudget()
         self.WALL_ARMS: bool = bool(
             config.get("scheduler.profile.arm-attribution", False)
         )

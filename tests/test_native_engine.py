@@ -662,7 +662,7 @@ def test_wall_bills_native_phase():
     engine.drain (dtpu_wall_seconds_total)."""
     _oracle, nat = _build_pair(seed=8, width=16, layers=2)
     _drive(nat, seed=8)
-    totals = nat.wall.totals
+    totals = nat.wall.snapshot()
     assert totals.get("engine.native", 0.0) > 0.0
     assert totals.get("engine.drain", 0.0) > 0.0
 
