@@ -16,8 +16,9 @@ engine removes both costs:
   with a power-of-two bucket shape for jit-cache reuse) and runs O(F + W)
   work, not O(T + E).  Consecutive small waves are fused into one
   ``lax.fori_loop`` dispatch (per-dispatch overhead dominates tiny
-  waves).  All dispatches are enqueued asynchronously back-to-back —
-  one host sync for the whole graph.
+  waves); a fused run is as wide as its widest wave's bucket, since
+  every wave of the run pays all F lanes.  All dispatches are enqueued
+  asynchronously back-to-back — one host sync for the whole graph.
 - **Transfers are small**: uploads are float16/int32 (16 bytes/task),
   the assignment is cast to int16 on device before download.
 
@@ -559,29 +560,31 @@ def _plan_runs(
     bucket_fn=None,
     small: int = SMALL_WAVE,
 ) -> list[tuple[int, list[int]]]:
-    """Group consecutive same-bucket waves into fused runs:
-    [(F, [wave,...])].  Small waves share the ``small`` bucket; larger
+    """Group consecutive waves into fused runs: [(F, [wave,...])].
+    Waves whose bucket is <= ``small`` fuse into one run; larger
     consecutive waves with the same power-of-two bucket fuse too — one
     fori_loop dispatch per group instead of one program per wave (the
-    separate-program overhead dominates mid-sized waves).  The sharded
-    planner (:func:`_plan_runs_sharded`) reuses this loop with a
-    per-shard ``bucket_fn``."""
+    separate-program overhead dominates mid-sized waves).  A run's F is
+    its widest wave's bucket: every wave of the run pays all F lanes,
+    so 2,000 waves of 500 tasks run 512 wide, not ``small`` wide.  The
+    sharded planner (:func:`_plan_runs_sharded`) reuses this loop with
+    a per-shard ``bucket_fn``."""
     if bucket_fn is None:
         bucket_fn = _bucket
     sizes = np.diff(offsets)
     runs: list[tuple[int, list[int]]] = []
     cur: list[int] = []
-    cur_f = 0
+    cur_key = cur_f = 0
     for w, f in enumerate(sizes):
         b = bucket_fn(int(f))
-        target = small if b <= small else b
-        if cur and target == cur_f:
+        key = small if b <= small else b  # all small waves share a group
+        if cur and key == cur_key:
             cur.append(w)
+            cur_f = max(cur_f, b)
             continue
         if cur:
             runs.append((cur_f, cur))
-        cur = [w]
-        cur_f = target
+        cur, cur_key, cur_f = [w], key, b
     if cur:
         runs.append((cur_f, cur))
     return runs
@@ -769,6 +772,28 @@ class _Span(TraceSpan):
 _SPAN_KEYS = ("topo_s", "fill_wait_s", "upload_s", "dispatch_s", "finalize_s")
 
 
+def _plan_streamed(offsets: np.ndarray, mesh):
+    """``(runs, sharded_runs)`` of :func:`place_graph_streamed`: the
+    fused runs at their whole width F, and with a mesh the same runs at
+    the per-shard width (``None`` without one)."""
+    if mesh is None:
+        return _plan_runs(offsets), None
+    n_shard = _mesh_shards(mesh)[2]
+    sharded_runs = _plan_runs_sharded(offsets, n_shard)
+    return [(Fl * n_shard, ws) for Fl, ws in sharded_runs], sharded_runs
+
+
+def _row_counts(runs, offsets: np.ndarray) -> dict:
+    """``wave_rows``, the task rows the runs place (every wave is in one
+    run), and ``padded_rows``, each run's F times its real waves (the
+    padding waves that round K up skip their body and count nothing):
+    how full the wave programs run."""
+    return {
+        "wave_rows": int(offsets[-1]),
+        "padded_rows": sum(F * len(ws) for F, ws in runs),
+    }
+
+
 def place_graph_streamed(
     durations,
     out_bytes,
@@ -829,6 +854,8 @@ def place_graph_streamed(
     encode, ``device_put`` + ``_apply_chunk``), ``dispatch_s`` (the
     wave runs) and ``finalize_s`` (the last download and the unpack).
     Each span shows as ``place.<phase>`` on any active profiler trace.
+    It also receives the counts ``wave_rows`` and ``padded_rows``
+    (:func:`_row_counts`): their ratio is how full the wave programs run.
 
     Returns ``(packed, result)``; ``packed``'s host arrays are fully
     filled by return time.
@@ -853,6 +880,9 @@ def place_graph_streamed(
                                 bandwidth=bandwidth, latency=latency)
         if timings is not None:
             timings["fmt"] = "f16"
+            timings.update(_row_counts(
+                _plan_streamed(packed.offsets, mesh)[0], packed.offsets
+            ))
         if mesh is not None:
             result = place_graph_leveled_sharded(
                 mesh, packed, nthreads, occupancy0, running,
@@ -889,13 +919,7 @@ def place_graph_streamed(
             raise ValueError("graph has a cycle")
         offsets = offsets_buf[: n_levels + 1].copy()
 
-        if mesh is not None:
-            n_shard = _mesh_shards(mesh)[2]
-            sharded_runs = _plan_runs_sharded(offsets, n_shard)
-            runs = [(Fl * n_shard, ws) for Fl, ws in sharded_runs]
-        else:
-            sharded_runs = None
-            runs = _plan_runs(offsets)
+        runs, sharded_runs = _plan_streamed(offsets, mesh)
         Tp = T + _compute_pad(T, runs, offsets)
         Lp = _bucket(n_levels + 1, floor=64)
         # host fill targets are Tp-sized with a zero tail so chunk windows
@@ -933,6 +957,7 @@ def place_graph_streamed(
     fmt = "packed" if (compact and Tp < _PACK_LIMIT) else "f16"
     if timings is not None:
         timings["fmt"] = fmt
+        timings.update(_row_counts(runs, offsets))
 
     C = min(chunk_rows, T)
     with _Span("place.upload", timings):

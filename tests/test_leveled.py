@@ -116,13 +116,23 @@ def test_pack_empty_and_single():
 
 
 def test_plan_runs_fuses_small_waves():
-    # 5 small waves then one big one then 2 small
+    # 5 small waves then one big one then 2 small: each fused run is as
+    # wide as its widest wave's bucket (512 here), not SMALL_WAVE
     offsets = np.cumsum([0, 10, 20, 30, 40, 50, SMALL_WAVE * 3, 10, 10])
     runs = _plan_runs(offsets.astype(np.int32))
-    assert runs[0] == (SMALL_WAVE, [0, 1, 2, 3, 4])
+    assert len(runs) == 3
+    assert runs[0] == (512, [0, 1, 2, 3, 4])
     assert runs[1][1] == [5]
     assert runs[1][0] > SMALL_WAVE
-    assert runs[2] == (SMALL_WAVE, [6, 7])
+    assert runs[2] == (512, [6, 7])
+    # a fused run that mixes buckets takes the widest; the run
+    # boundaries stay where the SMALL_WAVE grouping puts them
+    sizes = [600, 9000, SMALL_WAVE * 3, 600, 3000, SMALL_WAVE * 3, 600]
+    runs = _plan_runs(np.cumsum([0, *sizes]).astype(np.int32))
+    assert [ws for _, ws in runs] == [[0, 1], [2], [3, 4], [5], [6]]
+    assert [F for F, _ in runs] == [
+        SMALL_WAVE, SMALL_WAVE * 4, 4096, SMALL_WAVE * 4, 1024
+    ]
 
 
 # ------------------------------------------------------------- placement
@@ -228,3 +238,62 @@ def test_wide_graph_exercises_fused_and_big_waves():
     assert res.n_waves == 2
     counts = np.bincount(res.assignment, minlength=8)
     assert counts.max() / counts.mean() < 1.5
+
+
+# ------------------------------------------------------- fused run width
+
+
+def stencil_dag(rng, steps, chunks):
+    """``map_overlap`` time-stepping: chunk i of step s reads chunks
+    i-1..i+1 of step s-1, so every wave is ``chunks`` tasks wide."""
+    n = steps * chunks
+    durations = rng.uniform(0.05, 0.15, n).astype(np.float32)
+    out_bytes = np.full(n, 8e6, np.float32)
+    consumer = np.arange(chunks, n)
+    src, dst = [], []
+    for d in (-1, 0, 1):
+        keep = (consumer % chunks + d >= 0) & (consumer % chunks + d < chunks)
+        src.append(consumer[keep] - chunks + d)
+        dst.append(consumer[keep])
+    return (durations, out_bytes, np.concatenate(src).astype(np.int32),
+            np.concatenate(dst).astype(np.int32))
+
+
+@pytest.mark.parametrize("shape", ["stencil", "random"])
+def test_fused_run_width_does_not_change_the_placement(monkeypatch, shape):
+    """Padding lanes are masked and add exact zeros, so a fused run built
+    at its widest wave's bucket places exactly as at SMALL_WAVE."""
+    from distributed_tpu.ops import leveled
+
+    rng = np.random.default_rng(6)
+    if shape == "stencil":
+        graph = stencil_dag(rng, steps=40, chunks=500)
+    else:
+        graph = random_dag(rng, 5000)
+    packed = pack_graph(*graph, bandwidth=BW)
+    fleet = workers(16)
+    planned = leveled._plan_runs(packed.offsets)
+    assert any(F < SMALL_WAVE and len(ws) > 1 for F, ws in planned)
+    narrow = place_graph_leveled(packed, *fleet)
+
+    def at_small_wave(offsets):
+        return [(max(F, SMALL_WAVE), ws) for F, ws in planned]
+
+    monkeypatch.setattr(leveled, "_plan_runs", at_small_wave)
+    wide = place_graph_leveled(packed, *fleet)
+    validate_leveled(packed, narrow, graph[2], graph[3], fleet[2])
+    for field in ("assignment", "choice", "occupancy", "start_time"):
+        np.testing.assert_array_equal(getattr(narrow, field),
+                                      getattr(wide, field), err_msg=field)
+
+
+def test_streamed_counts_wave_and_padded_rows():
+    """40 waves of 500 tasks run as one fused run 512 wide."""
+    from distributed_tpu.ops.leveled import place_graph_streamed
+
+    graph = stencil_dag(np.random.default_rng(7), steps=40, chunks=500)
+    tm: dict = {}
+    place_graph_streamed(*graph, *workers(16), bandwidth=BW, min_stream=1,
+                         timings=tm)
+    assert tm["wave_rows"] == 20_000
+    assert tm["padded_rows"] == 40 * 512

@@ -95,18 +95,36 @@ def _place_run_args(Tp, Lp, K, W, sharding):
     )
 
 
-@pytest.mark.parametrize("which", ["largest", "fused-small"])
+def _stencil_1m_shapes():
+    """(Tp, Lp, runs) of 2,000 waves of 500 tasks (the map_overlap
+    stencil at 1M tasks): one fused run, as wide as a 500-task wave."""
+    from distributed_tpu.ops.leveled import _bucket, _compute_pad, _plan_runs
+
+    offsets = np.arange(0, 1_000_001, 500, dtype=np.int32)
+    T, L = int(offsets[-1]), len(offsets) - 1
+    runs = _plan_runs(offsets)
+    Tp = T + _compute_pad(T, runs, offsets)
+    return Tp, _bucket(L + 1, floor=64), [
+        (F, _bucket(len(w), floor=1)) for F, w in runs
+    ]
+
+
+@pytest.mark.parametrize("which", ["largest", "fused-small", "stencil"])
 def test_place_run_dag_1m(one_chip, dag_1m_shapes, which):
-    """The leveled engine's wave program at dag_1m's largest wave bucket
-    and at its fused small-wave shape, on 512 workers."""
-    from distributed_tpu.ops.leveled import SMALL_WAVE, _place_run
+    """The leveled engine's wave program at dag_1m's largest wave bucket,
+    at its fused run with the most waves, and at the 1M-task stencil's
+    one fused run of 500-task waves, on 512 workers."""
+    from distributed_tpu.ops.leveled import _place_run
 
     Tp, Lp, runs = dag_1m_shapes
     if which == "largest":
         F, K = max(runs)
+    elif which == "fused-small":
+        F, K = max(runs, key=lambda r: r[1])
     else:
-        F, K = max((r for r in runs if r[0] == SMALL_WAVE),
-                   key=lambda r: r[1])
+        Tp, Lp, runs = _stencil_1m_shapes()
+        assert runs == [(512, 2048)]
+        F, K = runs[0]
     args = _place_run_args(Tp, Lp, K, 512, one_chip)
     compiled = _place_run.lower(
         *args, F=F, K=K, uniform=True, fmt="f16"
