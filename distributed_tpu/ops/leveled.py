@@ -769,7 +769,8 @@ class _Span(TraceSpan):
 
 
 #: the ``timings`` keys of ``place_graph_streamed``'s spans
-_SPAN_KEYS = ("topo_s", "fill_wait_s", "upload_s", "dispatch_s", "finalize_s")
+_SPAN_KEYS = ("topo_s", "fill_wait_s", "upload_s", "tiles_s", "dispatch_s",
+              "finalize_s")
 
 
 def _plan_streamed(offsets: np.ndarray, mesh):
@@ -851,8 +852,10 @@ def place_graph_streamed(
     ``timings``, when given, receives the wire ``fmt`` and the seconds
     of the main thread's spans: ``topo_s`` (phase 1), ``fill_wait_s``
     (waiting on the fill thread), ``upload_s`` (device buffers, chunk
-    encode, ``device_put`` + ``_apply_chunk``), ``dispatch_s`` (the
-    wave runs) and ``finalize_s`` (the last download and the unpack).
+    encode, ``device_put`` + ``_apply_chunk``), ``tiles_s`` (the sharded
+    engine's ``[K, F]`` tile assembly and its sharded ``device_put``;
+    0.0 off the mesh), ``dispatch_s`` (enqueueing the wave runs) and
+    ``finalize_s`` (the last download and the unpack).
     Each span shows as ``place.<phase>`` on any active profiler trace.
     It also receives the counts ``wave_rows`` and ``padded_rows``
     (:func:`_row_counts`): their ratio is how full the wave programs run.
@@ -1056,15 +1059,18 @@ def place_graph_streamed(
             and int(offsets[runs[run_i][1][-1] + 1]) <= i1
         ):
             last = run_i == len(runs) - 1
-            with _Span("place.dispatch", timings):
-                if mesh is not None:
-                    # sharded: assemble [K, F] tiles from the host fill
-                    # arrays and ship each shard exactly its slice — the
-                    # async per-shard H2D overlaps both the pack fill
-                    # and the earlier runs' compute
-                    Fl, waves = sharded_runs[run_i]
-                    rs.dispatch(host_fill, Fl, waves, last=last)
-                else:
+            if mesh is not None:
+                # sharded: assemble [K, F] tiles from the host fill
+                # arrays and ship each shard exactly its slice — the
+                # async per-shard H2D overlaps both the pack fill and
+                # the earlier runs' compute
+                Fl, waves = sharded_runs[run_i]
+                with _Span("place.tiles", timings):
+                    tiles = rs.tiles(host_fill, Fl, waves)
+                with _Span("place.dispatch", timings):
+                    rs.dispatch(tiles, Fl, waves, last=last)
+            else:
+                with _Span("place.dispatch", timings):
                     F, waves = runs[run_i]
                     rs.dispatch(bufs, F, waves, last=last)
             run_i += 1
@@ -1190,9 +1196,10 @@ def _sharded_run_fn(mesh, Fl: int, K: int, W: int, uniform: bool,
 
     names, sizes, D = _mesh_shards(mesh)
 
-    def local(dur_g, heavy_g, heavy2_g, xp_g, xp2_g, xa_g,
-              assign, choices, load, spans, offs, fs, widxs,
-              nthreads, running, occ0):
+    # named as the device trace shows it: ``jit__place_run_sharded``
+    def _place_run_sharded(dur_g, heavy_g, heavy2_g, xp_g, xp2_g, xa_g,
+                           assign, choices, load, spans, offs, fs, widxs,
+                           nthreads, running, occ0):
         # task arrays: [K, Fl] local tiles; assign/choices/load/spans
         # replicated; fleet arrays are "workers"-axis shards when the
         # mirror feeds the kernel, else full replicated [W]
@@ -1351,7 +1358,7 @@ def _sharded_run_fn(mesh, Fl: int, K: int, W: int, uniform: bool,
 
     fleet_spec = P("workers") if fleet_sharded else P(None)
     fn = jax.shard_map(
-        local,
+        _place_run_sharded,
         mesh=mesh,
         in_specs=(
             P(None, names), P(None, names), P(None, names),
@@ -1427,15 +1434,31 @@ class _ShardedRunState(_RunState):
     _TASK_DTYPES = (np.float16, np.int32, np.int32,
                     np.float16, np.float16, np.float16)
 
-    def dispatch(self, host_bufs, Fl: int, waves: list[int],
-                 last: bool) -> None:
+    def tiles(self, host_bufs, Fl: int, waves: list[int]):
         """Assemble one fused run's [K, F] tiles from the Tp-sized host
-        arrays, ship them sharded, and enqueue the kernel."""
+        arrays and ship them sharded (async ``device_put``)."""
         import jax as _jax
 
+        F = Fl * self.D
+        K = _bucket(len(waves), floor=1)
+        tiles = []
+        for buf, dtype in zip(host_bufs, self._TASK_DTYPES):
+            tile = np.zeros((K, F), dtype)
+            for i, w in enumerate(waves):
+                off = int(self.packed.offsets[w])
+                tile[i] = buf[off: off + F]
+            tiles.append(tile)
+        if self.stats is not None:
+            per_shard = sum(K * Fl * t.dtype.itemsize for t in tiles)
+            for row in self.stats["shards"]:
+                row["h2d_bytes"] += per_shard
+        return _jax.device_put(tuple(tiles), self.task_sharding)
+
+    def dispatch(self, tiles, Fl: int, waves: list[int],
+                 last: bool) -> None:
+        """Enqueue the kernel on one fused run's shipped tiles
+        (:meth:`tiles`)."""
         packed = self.packed
-        D = self.D
-        F = Fl * D
         K = _bucket(len(waves), floor=1)
         offs = np.full(K, packed.n, np.int32)
         fs = np.zeros(K, np.int32)
@@ -1444,18 +1467,7 @@ class _ShardedRunState(_RunState):
             offs[i] = packed.offsets[w]
             fs[i] = self.sizes[w]
             widxs[i] = w
-        tiles = []
-        for buf, dtype in zip(host_bufs, self._TASK_DTYPES):
-            tile = np.zeros((K, F), dtype)
-            for i, w in enumerate(waves):
-                off = int(packed.offsets[w])
-                tile[i] = buf[off: off + F]
-            tiles.append(tile)
-        tiles = _jax.device_put(tuple(tiles), self.task_sharding)
         if self.stats is not None:
-            per_shard = sum(K * Fl * t.dtype.itemsize for t in tiles)
-            for row in self.stats["shards"]:
-                row["h2d_bytes"] += per_shard
             self.stats["runs"] += 1
         W = int(self.fleet[0].shape[0])
         fn = _sharded_run_fn(
@@ -1555,6 +1567,7 @@ def place_graph_leveled_sharded(
                           thr_h, run_h, occ_h,
                           fleet_dev=fleet_dev, stats=stats)
     for run_i, (Fl, waves) in enumerate(runs):
-        rs.dispatch(host_bufs, Fl, waves, last=run_i == len(runs) - 1)
+        rs.dispatch(rs.tiles(host_bufs, Fl, waves), Fl, waves,
+                    last=run_i == len(runs) - 1)
     rs.record_shard_ms()
     return rs.finalize()
