@@ -186,7 +186,10 @@ def load() -> ctypes.CDLL | None:
             _f32p, _i32p, _i32p,
             _i32p, _i32p, _i32p,
             _i32p, _i32p, _f32p, _i32p, _i32p,
+            ctypes.c_int32,
         ]
+        lib.graphpack_threads.restype = ctypes.c_int32
+        lib.graphpack_threads.argtypes = [ctypes.c_int64]
         lib.graphpack_fill.restype = None
         lib.graphpack_fill.argtypes = [
             ctypes.c_int64, ctypes.c_int64,

@@ -6,8 +6,9 @@ E edges to update indegrees, so a 28-level 1M-task graph costs 28 full
 O(T+E) sweeps regardless of how many tasks are actually ready.  This
 engine removes both costs:
 
-- **Topological levels are precomputed on the host** by a single O(T+E)
-  C++ pass (`native/graphpack.cpp`, ctypes; numpy fallback).  Tasks are
+- **Topological levels are precomputed on the host** by an O(T+E) C++
+  pass over the host's cores (`native/graphpack.cpp`, ctypes; numpy
+  fallback).  Tasks are
   sorted by (level, priority-index), so wave *w* is the contiguous slice
   ``[offsets[w], offsets[w+1])`` of the level-sorted arrays — the device
   never sees a dependency edge and keeps no indegree state.
@@ -820,8 +821,9 @@ def place_graph_streamed(
     until all six arrays have landed.  This driver pipelines all three
     phases:
 
-    - phase 1 (topology: edge passes + Kahn peel + counting sort) is the
-      only serial part — sorted order doesn't exist before it;
+    - phase 1 (topology: edge passes + Kahn peel + counting sort, on
+      ``graphpack_threads(T)`` host threads) overlaps nothing — sorted
+      order doesn't exist before it;
     - phase 2 (the per-row fill into level-sorted arrays) runs on a
       worker thread (the C call drops the GIL) in ``chunk_rows`` chunks;
     - the main thread uploads each finished chunk (async ``device_put``
@@ -858,7 +860,9 @@ def place_graph_streamed(
     ``finalize_s`` (the last download and the unpack).
     Each span shows as ``place.<phase>`` on any active profiler trace.
     It also receives the counts ``wave_rows`` and ``padded_rows``
-    (:func:`_row_counts`): their ratio is how full the wave programs run.
+    (:func:`_row_counts`): their ratio is how full the wave programs run;
+    and ``topo_threads``, the host threads of phase 1 (1: the serial
+    pass, which the numpy fallback and graphs under ~2M tasks take).
 
     Returns ``(packed, result)``; ``packed``'s host arrays are fully
     filled by return time.
@@ -883,6 +887,9 @@ def place_graph_streamed(
                                 bandwidth=bandwidth, latency=latency)
         if timings is not None:
             timings["fmt"] = "f16"
+            timings["topo_threads"] = (
+                1 if lib is None else lib.graphpack_threads(T)
+            )
             timings.update(_row_counts(
                 _plan_streamed(packed.offsets, mesh)[0], packed.offsets
             ))
@@ -896,7 +903,10 @@ def place_graph_streamed(
                                          running)
         return packed, result
 
-    # phase 1, the serial topology pass
+    # phase 1, the topology pass: nothing overlaps it
+    topo_threads = lib.graphpack_threads(T)
+    if timings is not None:
+        timings["topo_threads"] = topo_threads
     with _Span("place.topo", timings):
         i32p = ctypes.POINTER(ctypes.c_int32)
         f32p = ctypes.POINTER(ctypes.c_float)
@@ -916,7 +926,7 @@ def place_graph_streamed(
             offsets_buf.ctypes.data_as(i32p),
             heavy.ctypes.data_as(i32p), heavy2.ctypes.data_as(i32p),
             dep_total.ctypes.data_as(f32p), indeg.ctypes.data_as(i32p),
-            inv.ctypes.data_as(i32p),
+            inv.ctypes.data_as(i32p), topo_threads,
         )
         if n_levels < 0:
             raise ValueError("graph has a cycle")

@@ -8,6 +8,10 @@ the engine itself is validated against host oracles, not the reference.
 
 from __future__ import annotations
 
+import ctypes
+import functools
+import os
+
 import numpy as np
 import pytest
 
@@ -113,6 +117,170 @@ def test_pack_empty_and_single():
                    np.zeros(0, np.int32), np.zeros(0, np.int32))
     assert p.n_levels == 1
     assert p.offsets.tolist() == [0, 1]
+
+
+# ------------------------------------------- threaded topology pass
+
+
+def relabel(rng, graph):
+    """Tasks renamed by a random permutation, edges ordered by consumer,
+    as a scheduler's key order leaves them: every access by task id of
+    the topology pass lands somewhere else."""
+    durations, out_bytes, src, dst = graph
+    label = rng.permutation(len(durations)).astype(np.int32)
+    src, dst = label[src], label[dst]
+    order = np.argsort(dst, kind="stable")
+    return durations, out_bytes, src[order], dst[order]
+
+
+def messy_dag(rng, n):
+    """A random DAG whose edge list also holds self-loops, endpoints out
+    of range and duplicate edges, in shuffled order."""
+    durations, out_bytes, src, dst = random_dag(rng, n)
+    k = 5000
+    loops = rng.integers(0, n, k).astype(np.int32)
+    dup = rng.integers(0, len(src), k)
+    src = np.concatenate([src, loops, np.full(k, -1, np.int32),
+                          np.full(k, n + 5, np.int32), src[dup],
+                          rng.integers(0, n, k).astype(np.int32)])
+    dst = np.concatenate([dst, loops, rng.integers(0, n, k).astype(np.int32),
+                          rng.integers(0, n, k).astype(np.int32), dst[dup],
+                          np.full(k, -7, np.int32)])
+    order = rng.permutation(len(src))
+    return durations, out_bytes, src[order], dst[order]
+
+
+def topo_graph(name):
+    """The parity cases, each run at explicit thread counts (1 is the
+    serial pass).  Wide levels (>= 16,384 tasks) take the pool's split
+    peel, narrow ones the calling thread's; the chain has more levels
+    than per-thread level histograms may cost, so it sorts on one
+    range."""
+    rng = np.random.default_rng(11)
+    if name == "random":
+        return relabel(rng, random_dag(rng, 300_000))
+    if name == "stencil":
+        return stencil_dag(rng, steps=15, chunks=20_000)
+    if name == "chains":
+        n = 300_000  # 150 chains of 2,000: 2,000 levels of 150 tasks
+        src = np.arange(n - 150, dtype=np.int32)
+        graph = (rng.uniform(0.01, 1, n).astype(np.float32),
+                 rng.uniform(1e3, 1e7, n).astype(np.float32), src, src + 150)
+        return relabel(rng, graph)
+    if name == "chain":
+        n = 20_000
+        src = np.arange(n - 1, dtype=np.int32)
+        graph = (np.ones(n, np.float32),
+                 rng.uniform(1e3, 1e7, n).astype(np.float32), src, src + 1)
+        return relabel(rng, graph)
+    if name == "messy":
+        return messy_dag(rng, 300_000)
+    if name == "cycle":
+        durations, out_bytes, src, dst = relabel(rng, random_dag(rng, 300_000))
+        i = int(np.argmax(dst))  # some edge s -> d; add d -> ... -> s
+        return (durations, out_bytes, np.append(src, [dst[i]]),
+                np.append(dst, [src[i]]))
+    raise ValueError(name)
+
+
+def native_topo(graph, threads):
+    """``graphpack_topo`` on ``threads`` host threads (1: the serial
+    pass): ``(n_levels, outputs)``."""
+    from distributed_tpu import native
+
+    lib = native.load()
+    if lib is None:
+        pytest.skip("native library unavailable")
+    durations, out_bytes, src, dst = graph
+    T = len(durations)
+    out = {k: np.empty(T, np.int32) for k in
+           ("level", "perm", "heavy", "heavy2", "indeg", "inv")}
+    out["offsets"] = np.empty(T + 1, np.int32)
+    out["dep_total"] = np.empty(T, np.float32)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    f32p = ctypes.POINTER(ctypes.c_float)
+
+    def ptr(a):
+        return a.ctypes.data_as(f32p if a.dtype == np.float32 else i32p)
+
+    n_levels = lib.graphpack_topo(
+        T, len(src), ptr(out_bytes), ptr(src), ptr(dst),
+        ptr(out["level"]), ptr(out["perm"]), ptr(out["offsets"]),
+        ptr(out["heavy"]), ptr(out["heavy2"]), ptr(out["dep_total"]),
+        ptr(out["indeg"]), ptr(out["inv"]), threads,
+    )
+    out["offsets"] = out["offsets"][: n_levels + 1]
+    return n_levels, out
+
+
+@functools.lru_cache(maxsize=None)
+def topo_reference(name):
+    """``_pack_numpy``'s outputs, plus the per-task reductions it does
+    not return: indegree, the inverse permutation, and ``dep_total``
+    summed in float32 in edge order (``np.add.at`` is unbuffered)."""
+    durations, out_bytes, src, dst = graph = topo_graph(name)
+    T = len(durations)
+    level, perm, heavy, heavy2, _, offsets, L = _pack_numpy(
+        durations, out_bytes, src.astype(np.int64), dst.astype(np.int64)
+    )
+    keep = (src != dst) & (src >= 0) & (src < T) & (dst >= 0) & (dst < T)
+    dep_total = np.zeros(T, np.float32)
+    np.add.at(dep_total, dst[keep], out_bytes[src[keep]])
+    inv = np.empty(T, np.int32)
+    inv[perm] = np.arange(T, dtype=np.int32)
+    return graph, L, dict(
+        level=level, perm=perm, offsets=offsets, heavy=heavy, heavy2=heavy2,
+        dep_total=dep_total, indeg=np.bincount(dst[keep], minlength=T),
+        inv=inv,
+    )
+
+
+@pytest.mark.parametrize("threads", [1, 2, 3, 8, 32])
+@pytest.mark.parametrize("name",
+                         ["random", "stencil", "chains", "chain", "messy"])
+def test_threaded_topo_is_bit_identical(name, threads):
+    """Every output of the topology pass, at every thread count (32 is
+    more threads than most hosts have cores), equals the numpy fallback
+    bit for bit (``dep_total`` as float32 bits)."""
+    graph, L, ref = topo_reference(name)
+    n_levels, out = native_topo(graph, threads)
+    assert n_levels == L
+    for key, want in ref.items():
+        got = out[key]
+        if key == "dep_total":
+            got, want = got.view(np.int32), want.view(np.int32)
+        np.testing.assert_array_equal(got, want, err_msg=key)
+
+
+@pytest.mark.parametrize("threads", [1, 2, 3, 8])
+def test_threaded_topo_reports_a_cycle(threads):
+    assert native_topo(topo_graph("cycle"), threads)[0] == -1
+
+
+def test_streamed_topo_threads_engage_above_the_gate():
+    """A graph past the host's caches (2^21 tasks) runs the pass on a
+    pool; a small one keeps the serial pass.  Both place exactly as the
+    unstreamed engine does."""
+    from distributed_tpu import native
+    from distributed_tpu.ops.leveled import place_graph_streamed
+
+    lib = native.load()
+    if lib is None:
+        pytest.skip("native library unavailable")
+    fleet = workers(16)
+    for n, engaged in ((1 << 21, True), (20_000, False)):
+        if engaged and len(os.sched_getaffinity(0)) < 2:
+            continue  # one core: the pass stays serial
+        graph = random_dag(np.random.default_rng(12), n)
+        tm: dict = {}
+        _, streamed = place_graph_streamed(*graph, *fleet, bandwidth=BW,
+                                           min_stream=1, timings=tm)
+        assert (tm["topo_threads"] > 1) == engaged, (n, tm["topo_threads"])
+        whole = place_graph_leveled(pack_graph(*graph, bandwidth=BW), *fleet)
+        for field in ("assignment", "choice"):
+            np.testing.assert_array_equal(getattr(streamed, field),
+                                          getattr(whole, field),
+                                          err_msg=(n, field))
 
 
 def test_plan_runs_fuses_small_waves():
