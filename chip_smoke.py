@@ -6,8 +6,9 @@ result line) if any phase fails:
 
 1. identity: the default JAX device is a TPU and the native library
    loads;
-2. whole-graph placement at the north-star size: ``bench.build_graph``
-   (1M tasks, 0-2 dependencies each) onto 512 workers x 2 threads
+2. whole-graph placement at the north-star size: a seeded random graph
+   (1M tasks, 0-2 dependencies each, ``benchmark.graphs.random_graph``)
+   onto 512 workers x 2 threads
    through ``ops.leveled.place_graph_streamed`` — the call the scheduler
    makes — validated, and compared with the same call on the host CPU
    backend in this process;
@@ -42,8 +43,11 @@ import time
 
 import numpy as np
 
-#: cost-model bandwidth of every engine call here (bench.py's)
+#: cost-model bandwidth of every engine call here (distributed.yaml's
+#: scheduler.bandwidth)
 BANDWIDTH = 100e6
+#: workers of the one-chip and the four-chip whole-graph phases
+N_WORKERS, N10_WORKERS = 512, 4096
 
 def say(msg: str) -> None:
     print(f"# {msg}", flush=True)
@@ -214,7 +218,8 @@ def _node(salt, *deps):
 
 def random_dag(n_tasks: int, seed: int):
     """0-2 dependencies per task on uniformly random earlier tasks
-    (bench.build_graph's shape) and an integer salt per task."""
+    (the shape of the whole-graph phases) and an integer salt per
+    task."""
     rng = np.random.default_rng(seed)
     n_deps = rng.integers(0, 3, n_tasks)
     n_deps[0] = 0
@@ -535,18 +540,19 @@ def main(argv: list[str] | None = None) -> int:
     from distributed_tpu.ops.compile_cache import enable_compile_cache
 
     say(f"compile cache: {enable_compile_cache()}")
-    import bench
+    from benchmark.graphs import random_graph
 
     t0 = time.perf_counter()
+    n_tasks = 10_000_000 if opts.chips == 4 else 1_000_000
+    graph = random_graph(np.random.default_rng(0), n_tasks, 2, (0.01, 1.0),
+                         (1e3, 1e7))
     if opts.chips == 4:
-        graph = bench.build_graph_10m(np.random.default_rng(0))
         say(f"phase 4 graph built in {time.perf_counter() - t0:.1f} s")
-        out = phase_four_chips(graph, bench.N10_WORKERS, n_devices=4)
+        out = phase_four_chips(graph, N10_WORKERS, n_devices=4)
         say(f"phase 4 four chips: {json.dumps(out)}")
     else:
-        graph = bench.build_graph(np.random.default_rng(0))
         say(f"phase 2 graph built in {time.perf_counter() - t0:.1f} s")
-        out = phase_whole_graph(graph, bench.N_WORKERS)
+        out = phase_whole_graph(graph, N_WORKERS)
         say(f"phase 2 whole graph: {json.dumps(out)}")
         del graph
         t0 = time.perf_counter()

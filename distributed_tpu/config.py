@@ -171,7 +171,7 @@ defaults: dict[str, Any] = {
             # (engine.scalar-arm:<start>,<finish>): the sim.profile_run
             # payoff artifact turns this on; off by default because two
             # monotonic reads per transition are NOT free on the flood
-            # path (the <5% smoke gate covers the default config)
+            # path (test_overhead.py's <5% gates cover the default)
             "arm-attribution": False,
         },
         # measured-truth telemetry plane (telemetry.py;

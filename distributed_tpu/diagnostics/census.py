@@ -391,9 +391,10 @@ class RetentionSentinel:
     plus quiesce-edge residue checks.
 
     ``tick`` is the periodic entry (server ``PeriodicCallback`` at
-    ``scheduler.census.interval``; allocation-free per the bench-smoke
-    gate): it reads every cheap probe, folds the members-per-second
-    slope into an EWMA, and flags families whose slope stays above
+    ``scheduler.census.interval``; allocation-free per
+    tests/test_overhead.py): it reads every cheap probe, folds the
+    members-per-second slope into an EWMA, and flags families whose
+    slope stays above
     ``scheduler.census.slope-threshold`` while holding at least
     ``scheduler.census.min-count`` members — each flag emits ONE
     flight-recorder ``leak`` event (re-armed when the slope halves).

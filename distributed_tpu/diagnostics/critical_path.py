@@ -19,9 +19,9 @@ The walk telescopes exactly: segment ``k`` spans
 ``t_join(pred_k) .. t_join(k)``, so the phase sums add up to
 ``t_join(terminal) - path_start`` **by construction** — ``check()``
 (and the CLI ``--check`` flag) asserts the attribution sums to the
-run's makespan within tolerance, the acceptance gate the ``ledger``
-bench-smoke config runs on a simulated cluster where the virtual clock
-makes the identity exact.
+run's makespan within tolerance, the acceptance gate
+tests/test_ledger.py runs on a simulated cluster where the virtual
+clock makes the identity exact.
 
 The per-task ordering of queue vs transfer inside one decision window
 is not observed (the scheduler sees the decision and the completion) —
@@ -229,7 +229,7 @@ def critical_path(rows: Iterable[dict],
 def check(result: dict, tolerance: float = CHECK_TOLERANCE) -> None:
     """Assert the phase attribution sums to the makespan within
     ``tolerance`` (fractional).  Raises ``ValueError`` — the ``--check``
-    gate and the ``ledger`` bench-smoke acceptance bound."""
+    gate and tests/test_ledger.py's acceptance bound."""
     makespan = float(result["makespan"])
     total = sum(result["attribution"].values())
     bound = max(abs(makespan) * tolerance, 1e-9)

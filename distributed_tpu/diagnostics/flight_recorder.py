@@ -11,7 +11,7 @@ journal, schema).  This module is the offline half:
   (``SchedulerState.transitions_batch`` and the ``stimulus_*_batch``
   arms) and — from the same starting state — reproduces the identical
   transition stream (key, start, finish, order; asserted by
-  tests/test_observability.py and the bench-smoke ``trace`` gate).
+  tests/test_observability.py).
   This is the capture half of the ROADMAP item 1 deterministic
   simulator: a recorded flood is its replay substrate;
 - the CLI::

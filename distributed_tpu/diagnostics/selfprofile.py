@@ -37,9 +37,9 @@ cooperating pieces (docs/observability.md "Self-profiling"):
 
 Always-on budget: batch-level phase enters only (a handful of monotonic
 reads per engine pass), sampling at a low configurable rate
-(``scheduler.profile.interval``), arm attribution off by default.  The
-``selfprofile`` bench smoke gates sampling-on overhead <5% on the
-engine flood (tests/test_bench_smoke.py).
+(``scheduler.profile.interval``), arm attribution off by default.
+tests/test_overhead.py gates sampling-on overhead <5% on the engine
+flood.
 
 Covered by graft-lint's monotonic-time rule (diagnostics/**): every
 clock read here is the monotonic ``utils.misc.time``, and the watchdog

@@ -50,7 +50,7 @@ Lifecycle rules (the PR 7 link-leak lesson applied to rows):
 Zero per-decision allocation on the hot path: rows live in ONE flat
 preallocated ring mutated by slice assignment (the PR 6 flight-
 recorder pattern taken further — no per-slot list objects), gated by
-the ``ledger`` bench-smoke ``sys.getallocatedblocks`` check, and task
+the ``sys.getallocatedblocks`` check in tests/test_overhead.py, and task
 rows are joined by integer HANDLE parked on the TaskState
 (``ts.ledger_row``) instead of a key-hashed index.  With
 ``digest_enabled`` (the simulator turns it on), every finalized row
@@ -187,7 +187,7 @@ class DecisionLedger:
         self.enabled = bool(enabled)
         # ONE flat preallocated ring (row i lives at offset
         # (i & mask) * len(ROW_FIELDS)), mutated in place via slice
-        # assignment: no per-decision allocation (the bench-smoke
+        # assignment: no per-decision allocation (test_overhead.py's
         # ``sys.getallocatedblocks`` gate) and no per-slot list object
         # indirection on the hot path
         self._ring: list = list(_EMPTY_ROW) * n
@@ -235,9 +235,9 @@ class DecisionLedger:
     # ------------------------------------------------------------- filing
     #
     # file/join are THE hot path (one pair per task placed): positional
-    # signatures, inlined writes, locals over attributes — the ledger
-    # bench-smoke holds the whole pair under the 5% engine-flood budget
-    # and the sys.getallocatedblocks gate.
+    # signatures, inlined writes, locals over attributes —
+    # tests/test_overhead.py holds the whole pair under the 5%
+    # engine-flood budget and the sys.getallocatedblocks gate.
 
     def file(self, kind: str, key: str, prefix: str, worker: str,
              stim: str, pred_constant: float = 0.0,

@@ -3,7 +3,8 @@
 Scheduler co-processor (consumed via ``scheduler.jax.*`` config gates):
 
 - ``leveled``    — level-synchronous whole-graph placement (the north
-                   star: 1M-task DAGs in ~1 s vs ~400 s stock python)
+                   star: a 1M-task DAG in ~213 ms on one v5e chip,
+                   benchmark cell dag_1m_512w.random)
 - ``placement``  — batched decide_worker cost kernels + sharded variant
 - ``wavefront``  — round-1 wavefront placer (kept as oracle/fallback)
 - ``stealing``   — vectorized (victim, level, thief) steal selection

@@ -881,7 +881,7 @@ def place_graph_streamed(
     lib = native.load()
     if lib is None or T < min_stream:
         # fallback path: the whole pack is serial, and place.topo holds
-        # it, so callers (bench.py) never read a zero pack phase
+        # it, so callers never read a zero pack phase
         with _Span("place.topo", timings):
             packed = pack_graph(durations, out_bytes, src, dst,
                                 bandwidth=bandwidth, latency=latency)

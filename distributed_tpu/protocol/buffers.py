@@ -11,7 +11,7 @@ layer share:
   copies (any materialization of a payload-sized frame; the send path
   must record **zero**), pool traffic and compression volume.  Exported
   at ``/metrics`` as ``dtpu_wire_*`` (http/server.py) and asserted by
-  tests and the smoke bench.
+  tests/test_wire.py.
 - :class:`BufferPool` / :func:`recv_pool` — bounded pool of receive
   buffers keyed by power-of-two size class, with exact one-shot
   allocation for giants.  Ownership rule: a buffer goes back to the

@@ -436,7 +436,7 @@ class SchedulerMirror:
         Upload cost per call mirrors :meth:`device_view`, but accounted
         PER SHARD: nothing when no row changed since the last sharded
         sync (a fresh cycle ships zero fleet rows on every shard —
-        counter-asserted by the bench smoke gate), an O(dirty) scatter
+        counter-asserted in tests/test_mirror.py), an O(dirty) scatter
         grouped by owning shard otherwise, and a full per-shard pack
         only at first use, capacity growth or a mesh change.  Returns
         ``None`` when jax is unavailable or the mesh cannot divide the

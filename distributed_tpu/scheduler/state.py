@@ -1024,7 +1024,7 @@ class SchedulerState:
             )
             # task-level trace hop (sampled 1-in-N): name=finish, dest=start
             # — interned strings only, so the flood fast path allocates
-            # nothing (the bench-smoke "trace" gate enforces both the alloc
+            # nothing (tests/test_overhead.py enforces both the alloc
             # contract and the <5% traced-on overhead)
             self.trace.emit_task(
                 "transition", actual_finish, stimulus_id, key=key, dest=start

@@ -23,8 +23,8 @@ and no code path consulted during a run reads the wall clock.
 
 The virtual-time makespan a run reports is therefore a property of the
 workload + link profile + policies alone, immune to the host box's
-documented 2x wall-clock drift (PERF.md) — the perf-gate property the
-``sim`` bench-smoke config asserts on every PR.
+documented 2x wall-clock drift (PERF.md) — the property
+tests/test_sim.py's same-seed test asserts.
 """
 
 from __future__ import annotations

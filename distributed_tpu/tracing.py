@@ -19,11 +19,11 @@ Three consumers (docs/observability.md):
   ``transitions_batch`` offline with a bit-identical transition stream —
   the capture half of the ROADMAP item 1 simulator.
 
-Hot-loop contract (enforced by the ``trace`` bench-smoke gate): ring
-slots are preallocated lists mutated in place, ``emit`` performs no
-per-event allocation, task-level events sample 1-in-N
-(``scheduler.trace.sample``), and traced-on overhead on the engine flood
-smoke stays under 5%.
+Hot-loop contract (enforced by tests/test_overhead.py): ring slots are
+preallocated lists mutated in place, ``emit`` performs no per-event
+allocation, task-level events sample 1-in-N
+(``scheduler.trace.sample``), and traced-on overhead on an engine flood
+stays under 5%.
 
 This file is pure (no IO, no event loop, no threads): the sans-io
 engines may import it, and the monotonic-time lint covers it — every
@@ -161,7 +161,7 @@ class FlightRecorder:
         # keep the monotonic utils.misc.time.
         self.clock = time
         # preallocated slots, mutated in place: the fast path allocates
-        # nothing (gate: bench.py --smoke "trace" alloc check)
+        # nothing (test_overhead.py's trace_emit budget)
         self._slots: list[list] = [
             [0.0, "", "", "", "", 0, ""] for _ in range(size)
         ]

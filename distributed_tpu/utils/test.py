@@ -1,7 +1,7 @@
-"""Shared in-process test/bench doubles (reference utils_test.py idiom).
+"""Shared in-process test doubles (reference utils_test.py idiom).
 
-Kept inside the package so the bench harness and the test suite drive
-scheduler extensions through ONE stub instead of drifting copies.
+Kept inside the package so every test drives scheduler extensions
+through ONE stub instead of drifting copies.
 """
 
 from __future__ import annotations

@@ -729,8 +729,7 @@ def test_cli_determinism_clean_on_this_repo():
     """The determinism gate on its own: every decision/digest/journal
     surface in the real tree is free of hash-seed-ordered iteration
     (docs/determinism.md).  Split from the full-lint gate so a
-    determinism regression names its rule in the failure, and because
-    bench --smoke runs exactly this invocation."""
+    determinism regression names its rule in the failure."""
     proc = subprocess.run(
         [sys.executable, "-m", "distributed_tpu.analysis",
          "--rule", "determinism", "--format", "json",

@@ -648,6 +648,7 @@ async def test_local_cluster_teardown_census_clean():
             }
             s.census.audit()
             assert s.census.residue() == []
+            n_families = len(s.census.families)
             for w in cluster.workers:
                 for _ in range(100):
                     if not w.state.tasks:
@@ -655,3 +656,9 @@ async def test_local_cluster_teardown_census_clean():
                     await asyncio.sleep(0.05)
                 w.state.census.audit()
                 assert w.state.census.residue() == [], w.address
+                n_families += len(w.state.census.families)
+            # three roles' censuses, each of its whole state
+            assert n_families > 100, n_families
+            # the RPC twin serves the same truth
+            head = (await c.scheduler.get_census(deep=True))[0]
+            assert head["quiesced"] is True, head

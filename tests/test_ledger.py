@@ -396,6 +396,55 @@ def test_erred_task_joins_as_erred():
     assert state.ledger.open_rows == 0
 
 
+def _inc(x):
+    return x + 1
+
+
+def _sum(xs):
+    return sum(xs)
+
+
+def test_local_cluster_joins_every_decision():
+    """A live two-worker cluster over TCP: a 60-task flood and a
+    dependent graph leave every decision joined to its outcome, with
+    regret observed, and ``get_ledger`` serves the summary; the
+    critical path of the cluster's dump sums to its own makespan."""
+    import asyncio
+
+    from distributed_tpu.client.client import Client
+    from distributed_tpu.deploy.local import LocalCluster
+    from distributed_tpu.diagnostics.cluster_dump import DumpArtefact
+    from distributed_tpu.graph.spec import Graph, TaskRef, TaskSpec
+
+    async def run():
+        async with LocalCluster(n_workers=2, threads_per_worker=1) as cl:
+            async with Client(cl.scheduler_address) as c:
+                await c.gather(c.map(_inc, range(60)))
+                g = Graph()
+                for i in range(16):
+                    g.tasks[f"src-{i}"] = TaskSpec(_inc, (i,))
+                    g.tasks[f"dep-{i}"] = TaskSpec(
+                        _inc, (TaskRef(f"src-{i}"),))
+                g.tasks["root"] = TaskSpec(
+                    _sum, ([TaskRef(f"dep-{i}") for i in range(16)],))
+                futs = c.compute_graph(g, ["root"])
+                assert await futs["root"].result() == sum(range(16)) + 32
+                summary = cl.scheduler.state.ledger.summary()
+                rpc = await c.scheduler.get_ledger(n=10)
+                dump = await c.dump_cluster_state()
+        return summary, rpc, dump
+
+    summary, rpc, dump = asyncio.run(asyncio.wait_for(run(), 120))
+    assert summary["joined"] >= 60, summary
+    assert summary["unjoined"] == 0 and summary["open"] == 0, summary
+    assert summary["outcomes"].get("memory", 0) >= 60, summary
+    assert sum(k["count"] for k in summary["kinds"].values()) > 0, summary
+    assert rpc and rpc[0]["type"] == "ledger-summary"
+    cp = DumpArtefact(dump).critical_path()
+    assert cp is not None
+    check(cp, tolerance=0.01)
+
+
 # ------------------------------------------------------ offline tooling
 
 

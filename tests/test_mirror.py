@@ -374,15 +374,20 @@ def test_shared_fleet_view_feeds_steal_and_amm_without_repack():
     fv2 = m.fleet_view()
     after = m.stats()
     assert after["oracle_packs"] == 0
+    assert after["oracle_failures"] == 0
     assert after["rows_refreshed"] == base["rows_refreshed"]
+    # device residency: at most the one initial whole-fleet upload
+    assert after["full_uploads"] <= 1
+    assert after["rows_uploaded"] == base["rows_uploaded"] == 0, after
     assert fv1.slots is fv2.slots  # membership untouched, view reused
-    # the AMM round produced drop messages for the over-replicated keys
+    # the AMM round dropped replicas of the over-replicated keys
     assert any(
-        msg.get("op") == "remove-replicas"
+        msg.get("op") == "remove-replicas" and msg.get("keys")
         for _, wmsgs in sched.sent
         for msgs in wmsgs.values()
         for msg in msgs
     )
+    m.verify()
 
 
 # --------------------------------------- device steal comm-cost fidelity

@@ -16,6 +16,7 @@ import os
 import random
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -64,61 +65,69 @@ class _StepClock:
         return self.t
 
 
-def _build_pair(n_workers=32, width=64, layers=8, fanin=2, seed=0,
-                journal=False, restrictions=False, actors=False):
-    """(oracle, native) SchedulerStates with the identical graph."""
-    states = []
-    for native_on in (False, True):
-        with config.set(OVR):
-            state = SchedulerState(validate=False, clock=_StepClock())
-            state.ledger.digest_enabled = True
-            if native_on:
-                if not state.attach_native(build=True):
-                    pytest.skip("native toolchain unavailable")
-            if journal:
-                state.trace.journal_start()
-            for i in range(n_workers):
-                state.add_worker_state(
-                    f"sim://w{i}", nthreads=1, memory_limit=2**30,
-                    name=f"w{i}",
-                )
-            rng = random.Random(seed)
-            addrs = list(state.workers)
-            prev = []
-            for i in range(width):
-                k = f"root-{i}"
-                state.client_desires_keys([k], "c")
-                recs, cm, wm = state._transition(
-                    k, "memory", "scatter", nbytes=65536,
-                    worker=addrs[i % len(addrs)],
-                )
-                state._transitions(recs, cm, wm, "scatter")
-                prev.append(k)
-            tasks, deps, prios = {}, {}, {}
-            ann = {}
-            rank = 0
-            for j in range(layers):
-                layer = [f"L{j}-{i}" for i in range(width)]
-                for k in layer:
-                    deps[k] = {
-                        prev[rng.randrange(len(prev))]
-                        for _ in range(fanin)
-                    }
-                    tasks[k] = SPEC
-                    prios[k] = (rank,)
-                    rank += 1
-                    if restrictions and rng.random() < 0.1:
-                        ann[k] = {"workers": [addrs[rng.randrange(len(addrs))]],
-                                  "allow_other_workers": True}
-                prev = layer
-            state.update_graph_core(
-                tasks, deps, prev, client="c", priorities=prios,
-                annotations_by_key=ann or None,
-                actors=[k for k in tasks if actors and k.endswith("-0")],
-                stimulus_id="graph",
+def build_flood_state(native_on, n_workers=32, width=64, layers=8,
+                      fanin=2, seed=0, journal=False, restrictions=False,
+                      actors=False, audit=True):
+    """A SchedulerState holding ``width`` scattered roots and ``layers``
+    layers of ``width`` tasks on random earlier ones.  ``audit`` gives it
+    the parity harness's step clock and ledger digests; without them it
+    is the timed engine of the speedup test."""
+    with config.set(OVR):
+        state = SchedulerState(validate=False,
+                               **({"clock": _StepClock()} if audit else {}))
+        state.ledger.digest_enabled = audit
+        if native_on:
+            if not state.attach_native(build=True):
+                pytest.skip("native toolchain unavailable")
+        if journal:
+            state.trace.journal_start()
+        for i in range(n_workers):
+            state.add_worker_state(
+                f"sim://w{i}", nthreads=1, memory_limit=2**30,
+                name=f"w{i}",
             )
-        states.append(state)
-    return states
+        rng = random.Random(seed)
+        addrs = list(state.workers)
+        prev = []
+        for i in range(width):
+            k = f"root-{i}"
+            state.client_desires_keys([k], "c")
+            recs, cm, wm = state._transition(
+                k, "memory", "scatter", nbytes=65536,
+                worker=addrs[i % len(addrs)],
+            )
+            state._transitions(recs, cm, wm, "scatter")
+            prev.append(k)
+        tasks, deps, prios = {}, {}, {}
+        ann = {}
+        rank = 0
+        for j in range(layers):
+            layer = [f"L{j}-{i}" for i in range(width)]
+            for k in layer:
+                deps[k] = {
+                    prev[rng.randrange(len(prev))]
+                    for _ in range(fanin)
+                }
+                tasks[k] = SPEC
+                prios[k] = (rank,)
+                rank += 1
+                if restrictions and rng.random() < 0.1:
+                    ann[k] = {"workers": [addrs[rng.randrange(len(addrs))]],
+                              "allow_other_workers": True}
+            prev = layer
+        state.update_graph_core(
+            tasks, deps, prev, client="c", priorities=prios,
+            annotations_by_key=ann or None,
+            actors=[k for k in tasks if actors and k.endswith("-0")],
+            stimulus_id="graph",
+        )
+    return state
+
+
+def _build_pair(**kwargs):
+    """(oracle, native) SchedulerStates with the identical graph."""
+    return [build_flood_state(native_on, **kwargs)
+            for native_on in (False, True)]
 
 
 def _drive(state, seed=0, err_rate=0.0, release_at=None):
@@ -219,7 +228,11 @@ def test_multiflood_parity(seed):
     oracle, nat = _build_pair(seed=seed, journal=True)
     ro = _drive(oracle, seed=seed, release_at=3)
     rn = _drive(nat, seed=seed, release_at=3)
-    assert nat.native.counters()["transitions"] > 0, "native never ran"
+    c = nat.native.counters()
+    assert c["transitions"] > 0, "native never ran"
+    # the compiled arms absorb their share: under 10 % escape
+    assert c["escapes"] < 0.10 * (c["transitions"]
+                                  + c["oracle_transitions"]), c
     assert _snapshot(oracle) == _snapshot(nat)
     assert _stories(oracle) == _stories(nat)
     assert _canon(ro) == _canon(rn)
@@ -330,6 +343,67 @@ def test_no_introspection_flood_defers_fully():
     assert not ne._pending
     assert ne.hydrations > h0
     assert n_msgs > 0
+
+
+#: the timed engine: no flight recorder, no telemetry shadow
+TIMED = {**OVR, "scheduler.telemetry.enabled": False}
+
+
+def _timed_flood(state):
+    """Drive to quiescence: (whole-loop seconds, seconds inside the
+    stimulus calls alone, rows hydrated inside them).  Building a batch
+    reads ``ws.processing``, which hydrates the previous flood's
+    deferred segments; that stays outside the engine timer."""
+    ne = state.native
+    wall_engine, hydrated, rounds = 0.0, 0, 0
+    t_all = time.perf_counter()
+    while True:
+        batch = [
+            (
+                ts.key, ws.address, f"f{rounds}-{i}",
+                {"nbytes": 2048, "startstops": [{
+                    "action": "compute", "start": 0.0, "stop": 0.01,
+                }]},
+            )
+            for ws in state.workers.values()
+            for i, ts in enumerate(list(ws.processing))
+        ]
+        if not batch:
+            return time.perf_counter() - t_all, wall_engine, hydrated
+        h0 = ne.hydrations if ne is not None else 0
+        t0 = time.perf_counter()
+        state.stimulus_tasks_finished_batch(batch)
+        wall_engine += time.perf_counter() - t0
+        if ne is not None:
+            hydrated += ne.hydrations - h0
+        rounds += 1
+        assert rounds < 5000
+
+
+def test_native_flood_speedup_defers_inside_the_engine_timer():
+    """Against the oracle on the same flood, best of 5 pairs: >= 10x on
+    the engine plane (the stimulus calls alone) and >= 1.3x on the whole
+    loop with its batch building.  One noisy phase of the host shrinks
+    single pairs; a real regression drops every pair.  No row hydrates
+    inside the engine timer on a flood nothing reads."""
+
+    def run(native_on):
+        with config.set(TIMED):
+            return _timed_flood(build_flood_state(
+                native_on, layers=10, audit=False))
+
+    run(False)  # untimed warm-up on each arm
+    run(True)
+    loop_ratios, engine_ratios, hydrated = [], [], 0
+    for _ in range(5):
+        loop_o, engine_o, _ = run(False)
+        loop_n, engine_n, h = run(True)
+        loop_ratios.append(loop_o / loop_n)
+        engine_ratios.append(engine_o / engine_n)
+        hydrated += h
+    assert hydrated == 0, f"{hydrated} rows hydrated inside the engine"
+    assert max(engine_ratios) >= 10.0, engine_ratios
+    assert max(loop_ratios) >= 1.3, loop_ratios
 
 
 def test_parity_with_erred_floods_and_restrictions():

@@ -297,6 +297,76 @@ def test_loop_watchdog_single_capture_per_episode():
     assert events[0]["n"] >= 80  # lag in ms, at least the threshold
 
 
+@gen_test()
+async def test_loop_watchdog_names_blocking_coroutine_frame():
+    """A busy-wait inside a coroutine on a real event loop: exactly one
+    capture naming the blocking frame, one ``stall`` ring event, and a
+    watchdog that re-arms once the loop ticks again."""
+    from distributed_tpu.tracing import FlightRecorder
+
+    tr = FlightRecorder(enabled=True, ring_size=64)
+    wd = LoopWatchdog(trace=tr, interval=0.02, stall_threshold=0.12)
+    wd.start(threading.get_ident())
+
+    async def ticker():
+        while True:
+            wd.tick()
+            await asyncio.sleep(0.02)
+
+    tick_task = asyncio.create_task(ticker())
+    try:
+        await asyncio.sleep(0.1)  # healthy ticks first
+
+        def _block_loop():
+            t0 = _time.perf_counter()
+            while _time.perf_counter() - t0 < 0.35:
+                pass  # the loop's thread is pinned here
+
+        _block_loop()
+        await asyncio.sleep(0.3)  # recovery: the watchdog re-arms
+    finally:
+        tick_task.cancel()
+        wd.stop()
+    assert wd.stalls_total == 1
+    assert "_block_loop" in wd.stalls[0]["traceback"]
+    events = [e for e in tr.tail() if e["cat"] == "stall"]
+    assert len(events) == 1 and "_block_loop" in events[0]["key"]
+    assert wd.hist_lag.count > 0
+
+
+def test_engine_flood_samples_carry_phases_and_arm_attribution():
+    """A profiled engine flood: phase-stamped samples under
+    ``engine.drain``, whose wall is nonzero; with arm attribution on,
+    per-arm rows hold more than a fifth of the engine's wall (a floor
+    for a noisy host; tests/test_profile_run.py gates the 0.70 share on
+    the longer simulator table)."""
+    from test_overhead import flood, make_state
+
+    state = make_state()
+    main = threading.get_ident()
+    prof = ControlPlaneProfiler(idents=lambda: [main], wall=state.wall,
+                                interval=0.002)
+    prof.start()
+    try:
+        flood(state)
+    finally:
+        prof.stop()
+    assert state.wall.snapshot().get("engine.drain", 0.0) > 0.0
+    assert prof.total_samples > 0
+    assert any(k.startswith("phase:engine.drain")
+               for k in prof.get_profile()["children"])
+    assert any(ph == "engine.drain" for _, ph, _s in prof.samples)
+
+    with config.set({"scheduler.profile.arm-attribution": True}):
+        state = make_state()
+    flood(state)
+    totals = state.wall.snapshot()
+    arms = sum(v for k, v in totals.items()
+               if k.startswith("engine.scalar-arm:"))
+    assert arms > 0, "arm attribution produced no per-arm rows"
+    assert arms / (totals.get("engine.drain", 0.0) + arms) > 0.2, totals
+
+
 # ------------------------------------------------------------- live cluster
 
 

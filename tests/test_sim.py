@@ -152,6 +152,9 @@ def test_chaos_worker_death():
     sim, rep = scenario_worker_death(model=MODEL)
     assert rep["counters"]["workers_killed"] == 2
     assert rep["n_alive"] == rep["n_workers"] - 2
+    # the lost lineage recomputed: every wanted key done, none lost
+    assert rep["keys_done"] >= rep["keys_wanted"]
+    assert rep["keys_lost"] == 0
     # deterministic: the same scenario digests identically
     _sim2, rep2 = scenario_worker_death(model=MODEL)
     assert rep["digest"] == rep2["digest"]

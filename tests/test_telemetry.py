@@ -365,6 +365,63 @@ def test_steal_shadow_event_carries_stimulus():
 # ------------------------------------------------------------- live wire
 
 
+@gen_test(timeout=60)
+async def test_tcp_echo_link_samples_track_measured_bandwidth():
+    """A TCP echo through the real comm stack, each round trip filed as
+    one link sample the way ``_gather_dep`` files a fetch: the
+    collector's EWMA bandwidth lands within 2x of the echo's own MB/s,
+    and on loopback it is more than 1.5x away from the
+    ``scheduler.bandwidth`` constant (the constant's error, kept as a
+    checked artifact)."""
+    import time
+
+    import numpy as np
+
+    from distributed_tpu.comm.core import connect, listen
+    from distributed_tpu.protocol.serialize import Serialize
+    from distributed_tpu.telemetry import LinkTelemetry
+    from distributed_tpu.utils.misc import time as mono
+
+    async def echo(comm):
+        try:
+            while True:
+                msg = await comm.read()
+                await comm.write({"op": "ack", "data": msg["data"]})
+        except Exception:
+            pass
+
+    listener = listen("tcp://127.0.0.1:0", echo)
+    await listener.start()
+    comm = await connect(listener.contact_address)
+    collector = LinkTelemetry(enabled=True)
+    src, dst = listener.contact_address, "tcp://requester"
+    size, reps = 4 * 2**20, 4
+    payload = np.random.default_rng(0).integers(0, 256, size, dtype=np.uint8)
+    try:
+        await comm.write({"data": Serialize(payload)})
+        await comm.read()  # warm
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            m0 = mono()
+            await comm.write({"data": Serialize(payload)})
+            await comm.read()
+            collector.record(src, dst, size, mono() - m0)
+        echo_bw = size * reps / (time.perf_counter() - t0)
+    finally:
+        await comm.close()
+        listener.stop()
+    link = collector.links[(src, dst)]
+    assert link.bandwidth.count == reps
+    assert link.bytes_total == size * reps
+    measured = link.bandwidth.value
+    assert echo_bw / 2 <= measured <= echo_bw * 2, (measured, echo_bw)
+    ratio = measured / float(config.get("scheduler.bandwidth"))
+    assert ratio > 1.5 or ratio < 1 / 1.5, ratio
+    # the heartbeat delta's encode keeps the sample count
+    rows = collector.rows(collector.take())
+    assert rows and rows[0][4] == reps
+
+
 @gen_test(timeout=120)
 async def test_link_samples_both_ends_agree_over_tcp():
     """SATELLITE: get_data true-wire-bytes attribute to per-link samples

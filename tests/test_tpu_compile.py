@@ -53,9 +53,10 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def dag_1m_shapes():
-    """(Tp, Lp, runs) of bench.py's dag_1m graph as the streamed driver
-    plans it: padded task rows, padded wave count, fused (F, K) runs."""
-    import bench
+    """(Tp, Lp, runs) of the dag_1m graph (1M tasks, 0-2 random earlier
+    dependencies, seed 0) as the streamed driver plans it: padded task
+    rows, padded wave count, fused (F, K) runs."""
+    from benchmark.graphs import random_graph
     from distributed_tpu.ops.leveled import (
         _bucket,
         _compute_pad,
@@ -63,8 +64,9 @@ def dag_1m_shapes():
         pack_graph,
     )
 
-    packed = pack_graph(*bench.build_graph(np.random.default_rng(0)),
-                        bandwidth=bench.BANDWIDTH)
+    graph = random_graph(np.random.default_rng(0), 1_000_000, 2,
+                         (0.01, 1.0), (1e3, 1e7))
+    packed = pack_graph(*graph, bandwidth=100e6)
     runs = _plan_runs(packed.offsets)
     Tp = packed.n + _compute_pad(packed.n, runs, packed.offsets)
     Lp = _bucket(packed.n_levels + 1, floor=64)

@@ -118,23 +118,26 @@ async def _echo_forever(comm):
 
 @gen_test()
 async def test_tcp_send_path_records_zero_payload_copies():
-    """The acceptance contract: a >=1 MB payload crosses the TCP send
-    path with dtpu_wire_payload_copies == 0 — no bytes(frame), no
-    joins, straight memoryview hand-off to the transport."""
-    arr = np.random.default_rng(1).integers(0, 255, 2_000_000, dtype=np.uint8)
+    """The acceptance contract: a payload of any size (1 KB to 8 MB)
+    crosses the TCP send path with dtpu_wire_payload_copies == 0 — no
+    bytes(frame), no joins, straight memoryview hand-off to the
+    transport."""
     listener = await _echo_listener("tcp")
     comm = await connect(listener.contact_address)
     try:
-        before = WIRE.snapshot()
-        await comm.write({"op": "blob", "data": Serialize(arr)})
-        out = await comm.read()
-        after = WIRE.snapshot()
-        np.testing.assert_array_equal(out["data"], arr)
-        # the echo round trip covers BOTH sides' send paths (client and
-        # server live in this process): zero copies total
-        assert after["payload_copies"] - before["payload_copies"] == 0
-        assert after["bytes_sent"] - before["bytes_sent"] >= 2 * arr.nbytes
-        assert after["bytes_recv"] - before["bytes_recv"] >= 2 * arr.nbytes
+        for size in (1024, 65536, 2_000_000, 8 * 2**20):
+            arr = np.random.default_rng(1).integers(
+                0, 255, size, dtype=np.uint8)
+            before = WIRE.snapshot()
+            await comm.write({"op": "blob", "data": Serialize(arr)})
+            out = await comm.read()
+            after = WIRE.snapshot()
+            np.testing.assert_array_equal(out["data"], arr)
+            # the echo round trip covers BOTH sides' send paths (client
+            # and server live in this process): zero copies total
+            assert after["payload_copies"] - before["payload_copies"] == 0
+            assert after["bytes_sent"] - before["bytes_sent"] >= 2 * size
+            assert after["bytes_recv"] - before["bytes_recv"] >= 2 * size
     finally:
         await comm.close()
         listener.stop()
