@@ -18,6 +18,7 @@ carry a library newer than sources it was not built from.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import hashlib
 import json
 import logging
@@ -77,25 +78,49 @@ def _needs_build() -> bool:
 
 
 def _build() -> bool:
-    # the spec is taken before compiling: a source edited mid-build
-    # leaves a sidecar that no longer matches, so the next load rebuilds
-    spec = _build_spec()
-    cmd = ["g++", *_FLAGS, *_SOURCES, "-o", _LIB_PATH]
+    """Build the library unless another process has just built it.
+
+    Processes sharing the package directory (test workers, a server
+    and its tools) build one at a time under an exclusive lock on
+    ``<lib>.lock``, and each output lands by ``os.replace`` from a file
+    of its own: a process loading the library sees the old file or the
+    whole new one, never one another compiler is still writing."""
     try:
-        proc = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        logger.warning("native build failed to run: %s", e)
+        lock = open(_LIB_PATH + ".lock", "a")
+    except OSError as e:
+        logger.warning("native build cannot take its lock: %s", e)
         return False
-    if proc.returncode != 0:
-        logger.warning(
-            "native build failed:\n%s", proc.stderr.decode()[-2000:]
-        )
-        return False
-    try:
-        with open(_BUILDINFO_PATH, "w") as f:
-            json.dump(spec, f)
-    except OSError as e:  # stale-able but functional
-        logger.warning("could not record native buildinfo: %s", e)
+    with lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not _needs_build():
+            return True
+        # the spec is taken before compiling: a source edited mid-build
+        # leaves a sidecar that no longer matches, so the next load
+        # rebuilds
+        spec = _build_spec()
+        tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+        cmd = ["g++", *_FLAGS, *_SOURCES, "-o", tmp]
+        try:
+            proc = subprocess.run(cmd, capture_output=True, timeout=120)
+            if proc.returncode == 0:
+                os.replace(tmp, _LIB_PATH)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            logger.warning("native build failed to run: %s", e)
+            return False
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        if proc.returncode != 0:
+            logger.warning(
+                "native build failed:\n%s", proc.stderr.decode()[-2000:]
+            )
+            return False
+        try:
+            with open(_BUILDINFO_PATH + ".tmp", "w") as f:
+                json.dump(spec, f)
+            os.replace(_BUILDINFO_PATH + ".tmp", _BUILDINFO_PATH)
+        except OSError as e:  # stale-able but functional
+            logger.warning("could not record native buildinfo: %s", e)
     return True
 
 

@@ -38,8 +38,10 @@ from __future__ import annotations
 
 import ctypes
 import functools
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
@@ -855,14 +857,18 @@ def place_graph_streamed(
     of the main thread's spans: ``topo_s`` (phase 1), ``fill_wait_s``
     (waiting on the fill thread), ``upload_s`` (device buffers, chunk
     encode, ``device_put`` + ``_apply_chunk``), ``tiles_s`` (the sharded
-    engine's ``[K, F]`` tile assembly and its sharded ``device_put``;
-    0.0 off the mesh), ``dispatch_s`` (enqueueing the wave runs) and
-    ``finalize_s`` (the last download and the unpack).
+    engine's per-shard ``[K, Fl]`` blocks, built on host threads above
+    ``_TILE_POOL_LANES`` lanes a run, and their batched ``device_put``;
+    0.0 off the mesh, whose host fill arrays are never zero-filled),
+    ``dispatch_s`` (enqueueing the wave runs) and ``finalize_s`` (the
+    last download and the unpack).
     Each span shows as ``place.<phase>`` on any active profiler trace.
     It also receives the counts ``wave_rows`` and ``padded_rows``
     (:func:`_row_counts`): their ratio is how full the wave programs run;
-    and ``topo_threads``, the host threads of phase 1 (1: the serial
-    pass, which the numpy fallback and graphs under ~2M tasks take).
+    ``topo_threads``, the host threads of phase 1 (1: the serial
+    pass, which the numpy fallback and graphs under ~2M tasks take);
+    and ``tile_threads``, the most host threads any run's tile blocks
+    were built on (1: in line, under the gate; 0: off the mesh).
 
     Returns ``(packed, result)``; ``packed``'s host arrays are fully
     filled by return time.
@@ -893,14 +899,17 @@ def place_graph_streamed(
             timings.update(_row_counts(
                 _plan_streamed(packed.offsets, mesh)[0], packed.offsets
             ))
+        tile_threads = 0
         if mesh is not None:
-            result = place_graph_leveled_sharded(
-                mesh, packed, nthreads, occupancy0, running,
-                fleet_dev=fleet_dev, stats=stats,
-            )
+            rs = _run_sharded(mesh, packed, nthreads, occupancy0, running,
+                              fleet_dev, stats)
+            tile_threads = rs.tile_threads
+            result = rs.finalize()
         else:
             result = place_graph_leveled(packed, nthreads, occupancy0,
                                          running)
+        if timings is not None:
+            timings["tile_threads"] = tile_threads
         return packed, result
 
     # phase 1, the topology pass: nothing overlaps it
@@ -939,22 +948,17 @@ def place_graph_streamed(
         # (fixed length C, clamped into [0, Tp)) always slice cleanly; only
         # the tail needs zeroing — the fill chunks cover every row in [0, T)
         # and np.zeros over six 1M-row arrays costs real milliseconds of the
-        # serial phase on a one-core host.  The SHARDED dispatch assembles
-        # run tiles straight from these host arrays, and a tile window can
-        # overread rows the filler has not reached yet (bucket overshoot
-        # into the next wave) — those lanes are validity-masked on device
-        # but must not be garbage (NaN-free gathers), so the mesh path
-        # zero-fills everything up front.
+        # serial phase on a one-core host.  The SHARDED dispatch zeroes
+        # nothing here: its tiles read only the rows of their own waves
+        # (``_shard_block``), all filled before the run dispatches.
         dur_s = np.empty(Tp, np.float32)
         heavy_s = np.empty(Tp, np.int32)
         heavy2_s = np.empty(Tp, np.int32)
         xp_s = np.empty(Tp, np.float32)
         xp2_s = np.empty(Tp, np.float32)
         xa_s = np.empty(Tp, np.float32)
-        for _buf in (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s):
-            if mesh is not None:
-                _buf[:] = 0
-            else:
+        if mesh is None:
+            for _buf in (dur_s, heavy_s, heavy2_s, xp_s, xp2_s, xa_s):
                 _buf[T:] = 0
         packed = PackedGraph(
             perm=perm, level=level, offsets=offsets, n_levels=int(n_levels),
@@ -1061,17 +1065,17 @@ def place_graph_streamed(
                     )
                 bufs = _apply_chunk(bufs, jax.device_put(host),
                                     jnp.int32(start))
-        # dispatch every fused run whose rows have fully landed; its
-        # windows may read a few rows past i1 — still the zero fill,
-        # masked by the wave's validity lanes
+        # dispatch every fused run whose rows have fully landed; on one
+        # device its windows may read a few rows past i1 — still the
+        # zero fill, masked by the wave's validity lanes
         while (
             run_i < len(runs)
             and int(offsets[runs[run_i][1][-1] + 1]) <= i1
         ):
             last = run_i == len(runs) - 1
             if mesh is not None:
-                # sharded: assemble [K, F] tiles from the host fill
-                # arrays and ship each shard exactly its slice — the
+                # sharded: build each shard's [K, Fl] blocks from the
+                # host fill arrays and ship each to its device — the
                 # async per-shard H2D overlaps both the pack fill and
                 # the earlier runs' compute
                 Fl, waves = sharded_runs[run_i]
@@ -1090,6 +1094,8 @@ def place_graph_streamed(
         if mesh is not None:
             rs.record_shard_ms()
         result = rs.finalize()
+    if timings is not None:
+        timings["tile_threads"] = rs.tile_threads if mesh is not None else 0
     return packed, result
 
 
@@ -1383,16 +1389,68 @@ def _sharded_run_fn(mesh, Fl: int, K: int, W: int, uniform: bool,
     return jax.jit(fn, donate_argnums=(6, 7, 8, 9))
 
 
+#: a fused run's tile lanes (``K * F``) above which its per-shard blocks
+#: are built on the tile pool.  On an 8-core host with four devices the
+#: pool wins from 2^19 lanes (a [4, 131072] run's blocks and put: 5.4 ->
+#: 2.8 ms; the 10M-task mesh graph's eleven runs: 228 -> 79 ms) and ties
+#: or loses at 2^18 and below, where the hand-off and the GIL-bound row
+#: loop outweigh the copies it spreads ([16, 16384]: 4.3 -> 6.1 ms)
+_TILE_POOL_LANES = 1 << 18
+
+_tile_pool_lock = threading.Lock()
+_TILE_POOL: ThreadPoolExecutor | None = None
+
+
+def _host_cores() -> int:
+    """The cores this process may run on."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # pragma: no cover - no affinity API
+        return os.cpu_count() or 1
+
+
+def _tile_threads(n_jobs: int) -> int:
+    """Threads for ``n_jobs`` block builds: at most one a block, the
+    host's cores and 16."""
+    return min(n_jobs, _host_cores(), 16)
+
+
+def _tile_pool() -> ThreadPoolExecutor:
+    """The process's tile pool, made on first use (numpy's copies and
+    casts drop the GIL, so its threads copy in parallel)."""
+    global _TILE_POOL
+    with _tile_pool_lock:
+        if _TILE_POOL is None:
+            _TILE_POOL = ThreadPoolExecutor(
+                min(_host_cores(), 16), thread_name_prefix="leveled-tiles"
+            )
+        return _TILE_POOL
+
+
+def _shard_block(buf, dtype, K: int, Fl: int, lo: int, offs, fs):
+    """One device's ``[K, Fl]`` block of a fused run's tile: row ``i``
+    is lanes ``[lo, lo + Fl)`` of the wave at ``offs[i]`` with ``fs[i]``
+    real rows, cast to ``dtype`` and zero past the wave's end; rows
+    past the run's waves are zero."""
+    block = np.empty((K, Fl), dtype)
+    for i, (off, f) in enumerate(zip(offs, fs)):
+        n = min(max(f - lo, 0), Fl)
+        block[i, :n] = buf[off + lo: off + lo + n]
+        block[i, n:] = 0
+    block[len(offs):] = 0
+    return block
+
+
 class _ShardedRunState(_RunState):
     """Dispatch/download driver for the mesh-sharded engine.
 
     Differs from the single-device ``_RunState`` in the upload plane:
     instead of six persistent ``Tp``-sized device buffers written by
-    chunk, each fused run ships a ``[K, F]`` tile set placed with
+    chunk, each fused run ships a ``[K, F]`` tile set sharded with
     ``NamedSharding`` — every shard receives EXACTLY its ``[K, Fl]``
-    slice (per-shard H2D), and the async ``device_put`` overlaps the
-    transfer against earlier runs still computing.  Segmented D2H is
-    inherited unchanged.
+    block (per-shard H2D, :meth:`tiles`), and the async ``device_put``
+    overlaps the transfer against earlier runs still computing.
+    Segmented D2H is inherited unchanged.
     """
 
     def __init__(self, mesh, packed: PackedGraph, Tp: int, Lp: int,
@@ -1433,6 +1491,7 @@ class _ShardedRunState(_RunState):
         self.seg_from = 0
         self.SEG_MIN = max(packed.n // 4, 4096)
         self.stats = stats
+        self.tile_threads = 0  # the most threads a run's tiles used
         if stats is not None:
             stats["n_shards"] = self.D
             stats["runs"] = 0
@@ -1445,24 +1504,54 @@ class _ShardedRunState(_RunState):
                     np.float16, np.float16, np.float16)
 
     def tiles(self, host_bufs, Fl: int, waves: list[int]):
-        """Assemble one fused run's [K, F] tiles from the Tp-sized host
-        arrays and ship them sharded (async ``device_put``)."""
+        """Ship one fused run's six ``[K, F]`` task tiles, sharded.
+
+        Each device's ``[K, Fl]`` block is built on its own and written
+        once: row ``i`` holds the device's lanes of wave ``i``, cast to
+        the wire dtype, up to the wave's real rows, then zeros, and the
+        padding rows (``i >= len(waves)``) are zeros; the kernel masks
+        every such lane.  So no lane reads a host row outside its
+        wave's ``[offset, offset + f)``.  Above ``_TILE_POOL_LANES``
+        lanes the blocks are built on the tile pool.  One batched
+        ``device_put`` ships every block to its device (async)."""
         import jax as _jax
 
         F = Fl * self.D
         K = _bucket(len(waves), floor=1)
-        tiles = []
-        for buf, dtype in zip(host_bufs, self._TASK_DTYPES):
-            tile = np.zeros((K, F), dtype)
-            for i, w in enumerate(waves):
-                off = int(self.packed.offsets[w])
-                tile[i] = buf[off: off + F]
-            tiles.append(tile)
+        offs = [int(self.packed.offsets[w]) for w in waves]
+        fs = [int(self.sizes[w]) for w in waves]
+        index_map = self.task_sharding.addressable_devices_indices_map(
+            (K, F)
+        )
+        devices = list(index_map)
+        los = [idx[1].start or 0 for idx in index_map.values()]
+        jobs = [(a, lo) for a in range(len(host_bufs)) for lo in los]
+
+        def build(job):
+            a, lo = job
+            return _shard_block(host_bufs[a], self._TASK_DTYPES[a], K, Fl,
+                                lo, offs, fs)
+
+        threads = _tile_threads(len(jobs)) if K * F > _TILE_POOL_LANES else 1
+        self.tile_threads = max(self.tile_threads, threads)
+        if threads > 1:
+            # the pool runs min(jobs, its threads) of them at once
+            blocks = list(_tile_pool().map(build, jobs))
+        else:
+            blocks = [build(job) for job in jobs]
         if self.stats is not None:
-            per_shard = sum(K * Fl * t.dtype.itemsize for t in tiles)
+            per_shard = sum(K * Fl * np.dtype(dt).itemsize
+                            for dt in self._TASK_DTYPES)
             for row in self.stats["shards"]:
                 row["h2d_bytes"] += per_shard
-        return _jax.device_put(tuple(tiles), self.task_sharding)
+        shards = _jax.device_put(blocks, devices * len(host_bufs))
+        D = self.D
+        return tuple(
+            _jax.make_array_from_single_device_arrays(
+                (K, F), self.task_sharding, shards[a * D:(a + 1) * D]
+            )
+            for a in range(len(host_bufs))
+        )
 
     def dispatch(self, tiles, Fl: int, waves: list[int],
                  last: bool) -> None:
@@ -1548,27 +1637,25 @@ def place_graph_leveled_sharded(
     ``stats`` (optional dict) receives per-shard H2D bytes and kernel
     completion ms.
     """
+    return _run_sharded(mesh, packed, nthreads, occupancy0, running,
+                        fleet_dev, stats).finalize()
+
+
+def _run_sharded(mesh, packed: PackedGraph, nthreads, occupancy0, running,
+                 fleet_dev, stats) -> _ShardedRunState:
+    """Dispatch every fused run of ``packed`` over ``mesh``; the
+    returned state's ``finalize`` gives the result."""
     T = packed.n
-    names, sizes, D = _mesh_shards(mesh)
+    D = _mesh_shards(mesh)[2]
     runs = _plan_runs_sharded(packed.offsets, D)
     Tp = T + _compute_pad(
         T, [(Fl * D, ws) for Fl, ws in runs], packed.offsets
     )
     Lp = _bucket(packed.n_levels + 1, floor=64)
-
-    def pad_buf(arr, fill, dtype):
-        buf = np.empty(Tp, dtype)
-        buf[:T] = arr
-        buf[T:] = fill
-        return buf
-
+    # the tiles cast to the wire dtypes and read only their waves' rows
     host_bufs = (
-        pad_buf(packed.duration_s, 0, np.float16),
-        pad_buf(packed.heavy_s, 0, np.int32),   # pad 0: safe gather index
-        pad_buf(packed.heavy2_s, 0, np.int32),
-        pad_buf(packed.xfer_pref_s, 0, np.float16),
-        pad_buf(packed.xfer_pref2_s, 0, np.float16),
-        pad_buf(packed.xfer_all_s, 0, np.float16),
+        packed.duration_s, packed.heavy_s, packed.heavy2_s,
+        packed.xfer_pref_s, packed.xfer_pref2_s, packed.xfer_all_s,
     )
     wide, uniform, thr_h, run_h, occ_h = _worker_params(
         nthreads, occupancy0, running
@@ -1580,4 +1667,4 @@ def place_graph_leveled_sharded(
         rs.dispatch(rs.tiles(host_bufs, Fl, waves), Fl, waves,
                     last=run_i == len(runs) - 1)
     rs.record_shard_ms()
-    return rs.finalize()
+    return rs

@@ -279,9 +279,10 @@ def test_place_graph_streamed_fills_its_span_timings():
     spans = ("topo_s", "fill_wait_s", "upload_s", "dispatch_s",
              "finalize_s")
     assert set(tm) == {*spans, "tiles_s", "fmt", "wave_rows", "padded_rows",
-                       "topo_threads"}
+                       "topo_threads", "tile_threads"}
     assert all(tm[k] > 0 for k in spans)
     assert tm["tiles_s"] == 0.0  # off the mesh: no sharded tiles
+    assert tm["tile_threads"] == 0
     assert sum(tm[k] for k in spans) <= wall
 
 

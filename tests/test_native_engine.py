@@ -640,6 +640,68 @@ def test_needs_build_keys_on_source_content_not_mtime(tmp_path, monkeypatch):
     assert native._needs_build(), "a content edit went unnoticed"
 
 
+RACE_CHILD = r"""
+import ctypes, sys, time
+from distributed_tpu import native
+
+src, lib, delay = sys.argv[1], sys.argv[2], float(sys.argv[3])
+native._SOURCES = [src]
+native._LIB_PATH = lib
+native._BUILDINFO_PATH = lib + ".buildinfo"
+time.sleep(delay)
+assert not native._needs_build() or native._build(), "build failed"
+ctypes.CDLL(lib).tdigest_new
+print("LOADED")
+"""
+
+# a compiler that replaces its output as a linker does (unlink, then a
+# new file) and writes it in two halves, half a second apart
+SLOW_GXX = """#!{python}
+import os, sys, time
+out = sys.argv[sys.argv.index("-o") + 1]
+data = open({built!r}, "rb").read()
+if os.path.exists(out):
+    os.unlink(out)
+with open(out, "wb") as f:
+    f.write(data[: len(data) // 2])
+    f.flush()
+    time.sleep(0.5)
+    f.write(data[len(data) // 2:])
+"""
+
+
+def test_concurrent_builds_never_load_a_half_written_library(tmp_path):
+    """Processes that find the library missing at staggered times (test
+    workers starting together) build it one at a time, and none loads
+    a file another compiler is still writing: the compiler here writes
+    its output slowly, in two halves."""
+    src = tmp_path / "tdigest.cpp"
+    src.write_bytes(open(native._SOURCES[0], "rb").read())
+    built = str(tmp_path / "built.so")
+    subprocess.run(["g++", *native._FLAGS, str(src), "-o", built],
+                   check=True, timeout=120)
+    bin_dir = tmp_path / "bin"
+    bin_dir.mkdir()
+    gxx = bin_dir / "g++"
+    gxx.write_text(SLOW_GXX.format(python=sys.executable, built=built))
+    gxx.chmod(0o755)
+    lib = str(tmp_path / "race.so")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo,
+               PATH=f"{bin_dir}{os.pathsep}{os.environ['PATH']}")
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", RACE_CHILD, str(src), lib, str(0.15 * i)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, env=env,
+        )
+        for i in range(6)
+    ]
+    outs = [p.communicate(timeout=240) for p in procs]
+    for p, (out, err) in zip(procs, outs):
+        assert p.returncode == 0 and b"LOADED" in out, err.decode()[-2000:]
+    assert not [f for f in os.listdir(tmp_path) if f.endswith(".tmp")]
+
+
 def test_min_flood_routes_small_floods_to_oracle():
     """Floods below scheduler.native-engine.min-flood run the oracle
     (per-flood bridge overhead outweighs the savings there)."""

@@ -27,10 +27,11 @@ from distributed_tpu.ops.leveled import (
     place_graph_streamed,
     validate_leveled,
 )
+from distributed_tpu.ops import leveled
 from distributed_tpu.ops.partition import make_engine_mesh, shard_bucket
 from distributed_tpu import native
 
-from test_leveled import BW, random_dag, workers
+from test_leveled import BW, random_dag, relabel, stencil_dag, workers
 
 MESH_LAYOUTS = ["1x1", "2x1", "4x2", "8x1"]
 
@@ -124,6 +125,117 @@ def test_streamed_sharded_matches_oneshot_sharded():
     assert len(bytes_per_shard) == 1 and bytes_per_shard.pop() > 0
 
 
+def fanout_dag(rng):
+    """One root, 6,000 leaves, 60 reducers of 100 leaves, 6 of 10
+    reducers, one final task: five waves, so K = 8 where they fuse."""
+    n_leaf, n_mid, n_top = 6000, 60, 6
+    n = 1 + n_leaf + n_mid + n_top + 1
+    leaves = np.arange(1, 1 + n_leaf)
+    mids = 1 + n_leaf + np.arange(n_mid)
+    tops = mids[-1] + 1 + np.arange(n_top)
+    src = np.concatenate([np.zeros(n_leaf, np.int64), leaves,
+                          mids, tops])
+    dst = np.concatenate([leaves, mids[(leaves - 1) // 100],
+                          tops[np.arange(n_mid) // 10],
+                          np.full(n_top, n - 1)])
+    return (rng.uniform(0.01, 1.0, n).astype(np.float32),
+            rng.uniform(1e3, 1e7, n).astype(np.float32),
+            src.astype(np.int32), dst.astype(np.int32))
+
+
+TILE_GRAPHS = {
+    "random": lambda rng: random_dag(rng, 12_000),
+    "relabelled": lambda rng: relabel(rng, random_dag(rng, 8_000, 3)),
+    "stencil": lambda rng: stencil_dag(rng, steps=21, chunks=300),
+    "fanout": fanout_dag,
+}
+
+
+def _poisoned(buf, lo: int, hi: int):
+    """``buf`` with every row outside ``[lo, hi)`` made unusable: NaN,
+    or an index no array has."""
+    out = np.array(buf)
+    bad = np.nan if out.dtype.kind == "f" else np.iinfo(out.dtype).min
+    out[:lo] = bad
+    out[hi:] = bad
+    return out
+
+
+def _expected_tile(src, dtype, offsets, waves, K: int, F: int):
+    """A run's ``[K, F]`` tile as the kernel may be given it: each wave's
+    rows cast to the wire dtype, every other lane zero."""
+    tile = np.zeros((K, F), dtype)
+    for i, w in enumerate(waves):
+        a, b = offsets[w], offsets[w + 1]
+        tile[i, : b - a] = src[a:b]
+    return tile
+
+
+@needs_native
+@pytest.mark.parametrize("assembly", ["inline", "pooled"])
+@pytest.mark.parametrize("engine", ["streamed", "oneshot"])
+@pytest.mark.parametrize("graph", sorted(TILE_GRAPHS))
+@pytest.mark.parametrize("layout", ["1x1", "2x2", "4x2"])
+def test_shard_tiles_read_only_their_waves(layout, graph, engine, assembly,
+                                           monkeypatch):
+    """Every run's tiles hold its waves' rows and zeros, whoever builds
+    them: with each host row outside the run's waves (the ``Tp`` tail
+    too) poisoned before the tiles read it, the shipped tiles equal the
+    plain tile byte for byte, and the placement equals both one-shot
+    engines bit for bit.  In line and on the tile pool (the gate forced
+    either way) give the same bytes."""
+    mesh = _mesh_or_skip(layout)
+    D = _needed(layout)
+    g = TILE_GRAPHS[graph](np.random.default_rng(17))
+    nthreads, occ0, running = workers(24, stopped=(3,))
+    packed = pack_graph(*g, bandwidth=BW)
+    want = place_graph_leveled(packed, nthreads, occ0, running)
+    runs = leveled._plan_runs_sharded(packed.offsets, D)
+    sizes = np.diff(packed.offsets)
+    # the runs this graph gives have padding rows and narrow waves
+    assert any(leveled._bucket(len(ws), floor=1) > len(ws) for _, ws in runs)
+    assert any(sizes[w] < Fl * D for Fl, ws in runs for w in ws)
+
+    monkeypatch.setattr(leveled, "_TILE_POOL_LANES",
+                        -1 if assembly == "pooled" else 2**62)
+    shipped = []
+    tiles = leveled._ShardedRunState.tiles
+
+    def poisoned_tiles(self, host_bufs, Fl, waves):
+        lo = int(self.packed.offsets[waves[0]])
+        hi = int(self.packed.offsets[waves[-1] + 1])
+        out = tiles(self, tuple(_poisoned(b, lo, hi) for b in host_bufs),
+                    Fl, waves)
+        shipped.append((Fl, waves, [np.asarray(t) for t in out]))
+        return out
+
+    monkeypatch.setattr(leveled._ShardedRunState, "tiles", poisoned_tiles)
+    tm: dict = {}
+    if engine == "streamed":
+        packed, res = place_graph_streamed(
+            *g, nthreads, occ0, running, bandwidth=BW, chunk_rows=3_000,
+            min_stream=1, mesh=mesh, timings=tm,
+        )
+        threads = leveled._tile_threads(6 * D) if assembly == "pooled" else 1
+        assert tm["tile_threads"] == threads
+    else:
+        res = place_graph_leveled_sharded(mesh, packed, nthreads, occ0,
+                                          running)
+    for field in ("assignment", "choice", "occupancy", "start_time"):
+        np.testing.assert_array_equal(getattr(res, field),
+                                      getattr(want, field), err_msg=field)
+    assert [(Fl, ws) for Fl, ws, _ in shipped] == runs
+    srcs = (packed.duration_s, packed.heavy_s, packed.heavy2_s,
+            packed.xfer_pref_s, packed.xfer_pref2_s, packed.xfer_all_s)
+    dtypes = leveled._ShardedRunState._TASK_DTYPES
+    for Fl, waves, got in shipped:
+        K = leveled._bucket(len(waves), floor=1)
+        for src, dtype, tile in zip(srcs, dtypes, got):
+            ref = _expected_tile(src, dtype, packed.offsets, waves, K, Fl * D)
+            assert tile.dtype == ref.dtype and tile.shape == ref.shape
+            assert tile.tobytes() == ref.tobytes()
+
+
 def test_streamed_sharded_fallback_below_threshold():
     """Below min_stream the mesh path delegates to pack + one-shot
     sharded place — same results, no fill thread."""
@@ -134,11 +246,13 @@ def test_streamed_sharded_fallback_below_threshold():
     packed = pack_graph(durations, out_bytes, src, dst, bandwidth=BW)
     res0 = place_graph_leveled_sharded(mesh, packed, nthreads, occ0,
                                        running)
+    tm: dict = {}
     _, res1 = place_graph_streamed(
         durations, out_bytes, src, dst, nthreads, occ0, running,
-        bandwidth=BW, min_stream=1_000_000, mesh=mesh,
+        bandwidth=BW, min_stream=1_000_000, mesh=mesh, timings=tm,
     )
     np.testing.assert_array_equal(res1.assignment, res0.assignment)
+    assert tm["tile_threads"] == 1  # small tiles: built in line
 
 
 def test_stopped_workers_never_assigned_on_mesh():

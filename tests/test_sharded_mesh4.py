@@ -57,6 +57,7 @@ print(json.dumps({
         len({s.device for s in a.addressable_shards})
         for a in fleet_dev.values()),
     "timings": {k: v for k, v in tm.items() if k.endswith("_s")},
+    "tile_threads": tm["tile_threads"],
 }))
 """
 
@@ -81,3 +82,4 @@ def test_mesh4_placement_equals_the_reference(shape):
     assert out["fleet_devices"] == [4, 4, 4]
     tm = out["timings"]
     assert tm["tiles_s"] > 0 and tm["dispatch_s"] > 0
+    assert out["tile_threads"] >= 1
