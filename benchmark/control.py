@@ -6,8 +6,9 @@ that must fail it, beside the sound program on the same seeds.
 - placement cells: the program's own lower-precision path,
   ``place_graph_streamed(compact=True)`` (transfer costs as 8-bit log
   codes instead of float16), against the plain reference;
-- the live cell: every task computed in float32 instead of exact
-  integers, through the same cluster.
+- the live cells (a driver with a ``TASK``, the function every task
+  runs): every task computed in float32 instead of exact integers,
+  through the same cluster and the cell's own driver.
 
 Prints one line per seed and reading.  The benchmark's own runs never
 run this; ``tests/benchmark`` runs it at a small size.
@@ -50,19 +51,18 @@ def placement_readings(config, traffic, seed: int) -> dict:
                                                   != want))}
 
 
-def live_readings(config, traffic, seed: int, seconds: float,
+def live_readings(driver, config, traffic, seed: int, seconds: float,
                   devices) -> dict:
-    """wrong_results of a short live run, sound and with float32 tasks."""
-    from benchmark.drivers import live
-
+    """wrong_results of a short run of a live ``driver``, sound and with
+    float32 tasks."""
     out = {"seed": seed}
     for label, task in (("", graphs.node), ("control_", float32_node)):
-        live.TASK = task
+        driver.TASK = task
         try:
-            res = live.run(config, traffic, seed, seconds, False,
-                           devices, 0.0, run.Measurements())
+            res = driver.run(config, traffic, seed, seconds, False,
+                             devices, 0.0, run.Measurements())
         finally:
-            live.TASK = graphs.node
+            driver.TASK = graphs.node
         out[f"{label}wrong_results"] = dict(
             (n, v) for n, v, _ in res["checks"])["wrong_results"]
     return out
@@ -86,10 +86,11 @@ def main(argv=None) -> int:
     spec = run.resolve(opts.workload, opts.rehearse)
     devices = run.start_jax(spec["cell"]["chips"], opts.rehearse)
     print(json.dumps({"device": run.device_info(devices)}), flush=True)
+    driver = run.load_module("drivers", spec["entry"])
     for seed in (int(s) for s in opts.seeds.split(",")):
-        if spec["config"]["entry"] == "live":
-            out = live_readings(spec["config"], spec["traffic"], seed,
-                                opts.seconds, devices)
+        if hasattr(driver, "TASK"):
+            out = live_readings(driver, spec["config"], spec["traffic"],
+                                seed, opts.seconds, devices)
         else:
             out = placement_readings(spec["config"], spec["traffic"], seed)
         print(json.dumps(out), flush=True)

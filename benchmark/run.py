@@ -5,9 +5,11 @@
 Everything about a cell is data, found by name:
 
 - the cell in ``BENCHMARK.json`` names a configuration and a traffic mix;
-- the configuration is ``benchmark/configs/<config>.json``, whose
-  ``entry`` names the driver ``benchmark/drivers/<entry>.py``;
+- the configuration is ``benchmark/configs/<config>.json``;
 - the traffic mix is ``benchmark/traffic/<traffic>.json``;
+- the driver is ``benchmark/drivers/<entry>.py``, ``entry`` being the
+  traffic's own where it names one, else the configuration's (new
+  traffic on an existing configuration brings its driver as a new file);
 - each metric is read by ``benchmark/metrics/<metric>.py`` (or, for a
   split metric ``<base>.<part>``, by ``<base>.py``) from the
   measurements the driver leaves (see ``Measurements``).
@@ -65,7 +67,8 @@ def load_module(kind: str, name: str):
 
 
 def resolve(workload: str, rehearse: bool = False) -> dict:
-    """The cell, its configuration, its traffic and its metrics, by name."""
+    """The cell, its configuration, its traffic, its driver's ``entry``
+    and its metrics, by name."""
     bench = load_json("BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -83,6 +86,7 @@ def resolve(workload: str, rehearse: bool = False) -> dict:
 
     return {
         "cell": cell, "config": config, "traffic": traffic,
+        "entry": traffic.get("entry", config["entry"]),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
@@ -170,7 +174,7 @@ def main(argv: list[str] | None = None) -> int:
     spec = resolve(opts.workload, opts.rehearse)
     devices = start_jax(spec["cell"]["chips"], opts.rehearse)
     m = Measurements(peaks=peaks_for(devices[0].device_kind, opts.rehearse))
-    driver = load_module("drivers", spec["config"]["entry"])
+    driver = load_module("drivers", spec["entry"])
     out = driver.run(
         config=spec["config"], traffic=spec["traffic"], seed=opts.seed,
         seconds=opts.seconds, trace=bool(opts.trace), devices=devices,

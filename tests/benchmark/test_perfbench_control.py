@@ -39,11 +39,13 @@ def test_placement_control_fails(cell):
         assert out["control_misplaced_tasks"] > 0
 
 
-def test_live_control_fails():
+@pytest.mark.parametrize("cell", ["live_64w.dag", "live_64w.flood"])
+def test_live_control_fails(cell):
     import jax
 
-    spec = run.resolve("live_64w.dag", rehearse=True)
-    out = control.live_readings(spec["config"], spec["traffic"], 3, 1.0,
-                                jax.devices()[:1])
+    spec = run.resolve(cell, rehearse=True)
+    driver = run.load_module("drivers", spec["entry"])
+    out = control.live_readings(driver, spec["config"], spec["traffic"], 3,
+                                1.0, jax.devices()[:1])
     assert out["wrong_results"] == 0
     assert out["control_wrong_results"] > 0

@@ -108,8 +108,30 @@ def _live_fault(monkeypatch, fault):
 
 
 @pytest.mark.parametrize("fault", [None, "answer_altered", "half_left_out"])
-def test_live_faults(fault, no_chip_check, monkeypatch, capsys):
+@pytest.mark.parametrize("cell", ["live_64w.dag", "live_64w.flood"])
+def test_live_faults(cell, fault, no_chip_check, monkeypatch, capsys):
     if fault:
         _live_fault(monkeypatch, fault)
     # a window long enough to hold a device steal cycle and AMM round
-    assert _correct(capsys, "live_64w.dag", 3) is (fault is None)
+    assert _correct(capsys, cell, 3) is (fault is None)
+
+
+def test_flood_fails_with_the_planner_on(no_chip_check, monkeypatch,
+                                         capsys):
+    """The flood's bypass is checked: with a map above ``min-batch``
+    (512, the program's default) the planner runs and the run is not
+    correct."""
+    resolve = run.resolve
+
+    def planner_on(workload, rehearse=False):
+        spec = resolve(workload, rehearse)
+        spec["traffic"] = {**spec["traffic"], "map_tasks": 600}
+        return spec
+
+    monkeypatch.setattr(run, "resolve", planner_on)
+    run.main(["--workload", "live_64w.flood", "--seed", "5", "--seconds",
+              "3", "--trace", "0", "--rehearse"])
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["correct"] is False
+    assert out["checks"]["planner_plans"]["value"] > 0
+    assert out["checks"]["wrong_results"]["value"] == 0

@@ -80,9 +80,25 @@ def test_every_name_resolves_to_files():
     for w in b["workloads"]:
         spec = run.resolve(w["name"])
         assert spec["config"]["chips"] == w["chips"]
-        run.load_module("drivers", spec["config"]["entry"])
+        assert callable(run.load_module("drivers", spec["entry"]).run)
         for m in spec["end_to_end"] + spec["per_layer"]:
             assert callable(run.load_module("metrics", m["name"]).read)
+
+
+def test_existing_cells_keep_their_drivers():
+    """A traffic mix may name its own driver; the cells that came before
+    that still run their configuration's."""
+    run = _run_module(REPO)
+    drivers = {"dag_1m_512w.random": "place", "live_64w.dag": "live",
+               "dag_1m_512w.stencil": "place",
+               "dag_10m_4096w.mesh": "place_mesh",
+               "live_64w.flood": "flood"}
+    for cell, entry in drivers.items():
+        spec = run.resolve(cell)
+        assert spec["entry"] == entry
+        if cell != "live_64w.flood":
+            assert "entry" not in spec["traffic"]
+            assert spec["entry"] == spec["config"]["entry"]
 
 
 def test_readers_return_nothing_without_input():
@@ -140,3 +156,38 @@ def test_a_new_cell_is_found_from_new_files_alone(tmp_path):
         run.resolve("no.such_cell")
     with pytest.raises(run.Refused):
         run.load_module("metrics", "no_such_metric")
+
+
+def test_new_traffic_brings_its_own_driver(tmp_path):
+    """A later PR adds a traffic mix with its own ``entry`` on an existing
+    configuration, and that driver, as new files and BENCHMARK.json
+    entries alone: the harness's files and the configuration's driver do
+    not change."""
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(REPO, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    kept = [os.path.relpath(os.path.join(d, f), tmp_path)
+            for d, _, fs in os.walk(tmp_path / "benchmark") for f in fs]
+    before = {p: (tmp_path / p).read_bytes() for p in kept}
+    (tmp_path / "benchmark/traffic/burst.json").write_text(json.dumps({
+        "kind": "live", "entry": "burst", "clients": 2,
+        "why": "bursts of submissions",
+    }))
+    (tmp_path / "benchmark/drivers/burst.py").write_text(
+        "TASK = None\n\n\ndef run(**kw):\n    return kw['traffic']\n")
+    b = _bench(tmp_path)
+    b["workloads"].append({"name": "live_64w.burst", "config": "live_64w",
+                           "traffic": "burst", "chips": 1, "why": "x"})
+    b["end_to_end"][-1]["workloads"].append("live_64w.burst")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    run = _run_module(tmp_path)
+    spec = run.resolve("live_64w.burst")
+    assert spec["entry"] == "burst"
+    assert spec["config"]["entry"] == "live"
+    assert spec["config"]["workers"] == 64
+    driver = run.load_module("drivers", spec["entry"])
+    assert driver.run(traffic=spec["traffic"])["clients"] == 2
+    assert [m["name"] for m in spec["end_to_end"]] == ["setup_s",
+                                                       "tasks_per_s"]
+    assert run.resolve("live_64w.dag")["entry"] == "live"
+    assert all((tmp_path / p).read_bytes() == v for p, v in before.items())
